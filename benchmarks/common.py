@@ -63,10 +63,11 @@ class Timer:
 
 
 def make_dp_algorithm(setting: str, alg: str, *, clip: float, clients: int,
-                      dim: int):
+                      dim: int, backend: str = "auto"):
     """Setting -> algorithm factory shared by e1/e2 (the paper's protocol:
     sigma = 5C/sqrt(M) for CDP, 0.7C for LDP Gaussian, eps0=eps1=eps2=2 for
-    PrivUnit); ``alg`` is "fedexp" or "fedavg"."""
+    PrivUnit); ``alg`` is "fedexp" or "fedavg".  ``backend`` picks the
+    Gaussian settings' clip/noise/reduce path (``fused_clip_aggregate``)."""
     import math as _math
 
     from repro.core.fedexp import make_algorithm
@@ -75,10 +76,11 @@ def make_dp_algorithm(setting: str, alg: str, *, clip: float, clients: int,
         name = "cdp-fedexp" if alg == "fedexp" else "dp-fedavg-cdp"
         return make_algorithm(name, clip_norm=clip,
                               sigma=5 * clip / _math.sqrt(clients),
-                              num_clients=clients)
+                              num_clients=clients, backend=backend)
     if setting == "ldp-gauss":
         name = "ldp-fedexp-gauss" if alg == "fedexp" else "dp-fedavg-ldp-gauss"
-        return make_algorithm(name, clip_norm=clip, sigma=0.7 * clip)
+        return make_algorithm(name, clip_norm=clip, sigma=0.7 * clip,
+                              backend=backend)
     if setting == "ldp-privunit":
         name = "ldp-fedexp-privunit" if alg == "fedexp" else "dp-fedavg-privunit"
         return make_algorithm(name, clip_norm=clip, eps0=2.0, eps1=2.0,

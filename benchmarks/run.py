@@ -36,6 +36,8 @@ def main() -> None:
     ap.add_argument("--json", action="store_true",
                     help="emit results/bench/<name>.json per benchmark")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     which = set(args.only) if args.only else set(ALL)
     if args.quick and not args.only and "e2" in which:
         # the CNN cells compile for ~100 s EACH on a 2-vCPU CI box (seed

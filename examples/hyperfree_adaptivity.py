@@ -19,7 +19,9 @@ import jax.numpy as jnp
 from repro.core.fedexp import make_algorithm
 from repro.data.synthetic import distance_to_opt, linreg_loss, make_synthetic_linreg
 from repro.fedsim import FederatedSession, TrainSpec
+from repro.launch.compile_cache import use_compile_cache
 
+use_compile_cache()
 D, TAU, ROUNDS, CLIP, ETA_L = 200, 20, 30, 0.3, 0.1
 
 print(f"{'M':>6} {'sigma_mult':>10} {'mean eta_g':>10} {'final dist':>11}")

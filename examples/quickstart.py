@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from repro.core.fedexp import make_algorithm
 from repro.data.synthetic import distance_to_opt, linreg_loss, make_synthetic_linreg
 from repro.fedsim import CohortSpec, FederatedSession, TrainSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.telemetry import JsonlTracker
 
 # grid-searched on this generation (EXPERIMENTS.md): (eta_l, C) per algorithm
@@ -110,6 +111,7 @@ def main(quick: bool = False, sampled_q: float | None = None,
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small geometry for CI smoke runs")

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.serve import ServeEngine
 from repro.models.transformer import DecoderLM
 
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

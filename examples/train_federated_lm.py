@@ -26,6 +26,7 @@ import numpy as np
 
 from repro import checkpoint as ckpt
 from repro.configs import ARCHS, FederatedConfig, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.rules import count_params
 from repro.launch.train import FederatedTrainer
 from repro.models.transformer import DecoderLM
@@ -114,4 +115,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

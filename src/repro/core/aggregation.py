@@ -55,6 +55,7 @@ __all__ = [
     "RoundStats",
     "RoundMoments",
     "aggregate_stats",
+    "sum_dot",
     "fused_clip_aggregate",
     "partial_clip_moments",
     "streamed_clip_moments",
@@ -65,6 +66,18 @@ __all__ = [
 ]
 
 _EPS = 1e-12
+
+
+def sum_dot(v: jax.Array, x: jax.Array) -> jax.Array:
+    """``v @ x`` at full f32 precision: a weighted sum over rows.
+
+    Exact sums over clients are written as matvecs (``ones @ u``, ``mask @
+    x``) because XLA:CPU's BLAS matvec is fast and matches the reference
+    reduction order bit-for-bit.  At default precision the TPU's MXU
+    multiplies f32 in bf16, a 2**-9 relative error that swamps Eq. 6's
+    ``mean_sq - d * sigma**2``; ``HIGHEST`` keeps f32, and is a no-op on CPU.
+    """
+    return jnp.matmul(v, x, precision=jax.lax.Precision.HIGHEST)
 
 
 def global_client_indices(start, m: int) -> jax.Array:
@@ -143,7 +156,7 @@ def _colmean(updates: jax.Array) -> jax.Array:
     """Column mean via matvec: XLA:CPU's axis-0 reduce is ~15x slower."""
     m = updates.shape[0]
     ones = jnp.ones((m,), jnp.float32)
-    return (ones @ updates) / m
+    return sum_dot(ones, updates) / m
 
 
 def aggregate_stats(updates: jax.Array) -> RoundStats:
@@ -364,27 +377,27 @@ def partial_clip_moments(
         # scalar sums are the DENSE clipped values (exact step-size inputs)
         if row_weights is not None:
             v = gate * row_weights
-            sum_sq_clipped = v @ (sq_norms * jnp.square(scale))
-            return RoundMoments(sum_c=v @ comp, sum_sq=sum_sq_clipped,
+            sum_sq_clipped = sum_dot(v, sq_norms * jnp.square(scale))
+            return RoundMoments(sum_c=sum_dot(v, comp), sum_sq=sum_sq_clipped,
                                 sum_sq_clipped=sum_sq_clipped, count=count)
         sum_sq_clipped = jnp.sum(sq_norms * jnp.square(scale))
         ones = jnp.ones((m,), jnp.float32)
-        return RoundMoments(sum_c=ones @ comp, sum_sq=sum_sq_clipped,
+        return RoundMoments(sum_c=sum_dot(ones, comp), sum_sq=sum_sq_clipped,
                             sum_sq_clipped=sum_sq_clipped, count=count)
     clipped = raw_updates * scale[:, None]
     released = clipped if noise is None else clipped + noise
     if row_weights is not None:
         v = gate * row_weights
-        sum_sq_clipped = v @ (sq_norms * jnp.square(scale))
+        sum_sq_clipped = sum_dot(v, sq_norms * jnp.square(scale))
         sum_sq = (sum_sq_clipped if noise is None
-                  else v @ jnp.sum(jnp.square(released), axis=-1))
-        return RoundMoments(sum_c=v @ released, sum_sq=sum_sq,
+                  else sum_dot(v, jnp.sum(jnp.square(released), axis=-1)))
+        return RoundMoments(sum_c=sum_dot(v, released), sum_sq=sum_sq,
                             sum_sq_clipped=sum_sq_clipped, count=count)
     sum_sq_clipped = jnp.sum(sq_norms * jnp.square(scale))
     sum_sq = (sum_sq_clipped if noise is None
               else jnp.sum(jnp.sum(jnp.square(released), axis=-1)))
     ones = jnp.ones((released.shape[0],), jnp.float32)
-    return RoundMoments(sum_c=ones @ released, sum_sq=sum_sq,
+    return RoundMoments(sum_c=sum_dot(ones, released), sum_sq=sum_sq,
                         sum_sq_clipped=sum_sq_clipped, count=count)
 
 
@@ -522,7 +535,7 @@ def raw_moments(deltas: jax.Array, mask: jax.Array | None,
         deltas = jnp.where(mask[:, None] > 0, deltas, 0.0)
         v = mask if row_weights is None else mask * row_weights
         count = jnp.sum(v)
-    sum_sq = v @ jnp.sum(jnp.square(deltas), axis=-1)
+    sum_sq = sum_dot(v, jnp.sum(jnp.square(deltas), axis=-1))
     rows = deltas if compress_fn is None else compress_fn(deltas)
-    return RoundMoments(sum_c=v @ rows, sum_sq=sum_sq,
+    return RoundMoments(sum_c=sum_dot(v, rows), sum_sq=sum_sq,
                         sum_sq_clipped=sum_sq, count=count)

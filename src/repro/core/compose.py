@@ -69,6 +69,7 @@ from repro.core.aggregation import (
     materialize_ldp_noise,
     partial_clip_moments,
     raw_moments,
+    sum_dot,
 )
 from repro.core.algorithm import (
     RoundAux,
@@ -353,7 +354,7 @@ class PerClientGaussian(PrivacyMechanism):
                                    backend=self.backend)
         v = mask if row_weights is None else mask * row_weights
         sig_sq = jnp.square(self._sigma_rows(start, deltas.shape[0]))
-        return mom, {"sum_sigma_sq": v @ sig_sq}
+        return mom, {"sum_sigma_sq": sum_dot(v, sig_sq)}
 
     def finalize(self, key, mom, extras, clip, m_eff):
         """Globally reduced moments -> the ``RoundStats`` the step layer consumes."""
@@ -458,11 +459,11 @@ class PrivUnitLDP(PrivacyMechanism):
         # unsharded reference reductions (see ``raw_moments``)
         v = mask if row_weights is None else mask * row_weights
         mom = RoundMoments(
-            sum_c=v @ released,
-            sum_sq=v @ jnp.sum(jnp.square(released), axis=-1),
-            sum_sq_clipped=v @ jnp.sum(jnp.square(clipped), axis=-1),
+            sum_c=sum_dot(v, released),
+            sum_sq=sum_dot(v, jnp.sum(jnp.square(released), axis=-1)),
+            sum_sq_clipped=sum_dot(v, jnp.sum(jnp.square(clipped), axis=-1)),
             count=jnp.sum(v))
-        return mom, {"sum_s_hat": v @ self._s_hat(released, clip)}
+        return mom, {"sum_s_hat": sum_dot(v, self._s_hat(released, clip))}
 
     def finalize(self, key, mom, extras, clip, m_eff):
         """Globally reduced moments -> the ``RoundStats`` the step layer consumes."""
@@ -1351,7 +1352,7 @@ class ComposedAlgorithm(ServerAlgorithm):
             below = (norms <= clip).astype(jnp.float32)
             extras = dict(extras)
             extras["count_below"] = (jnp.sum(below) if mask is None
-                                     else mask @ below)
+                                     else sum_dot(mask, below))
         if self.aggregation.is_weighted:
             # under weighted aggregation mom.count is a weight SUM; the
             # clip-quantile update and any realized-cohort noise need the

@@ -53,6 +53,7 @@ from repro.core.aggregation import (
     materialize_ldp_noise,
     partial_clip_moments,
     raw_moments as _raw_moments,
+    sum_dot,
 )
 from repro.core.algorithm import (
     RoundAux,
@@ -241,9 +242,9 @@ class DPFedAvgPrivUnit(ServerAlgorithm):
         # dots with the mask, not sum(mask * x): bit-parity with the
         # unsharded reference reductions (see _raw_moments)
         mom = RoundMoments(
-            sum_c=mask @ released,
-            sum_sq=mask @ jnp.sum(jnp.square(released), axis=-1),
-            sum_sq_clipped=mask @ jnp.sum(jnp.square(clipped), axis=-1),
+            sum_c=sum_dot(mask, released),
+            sum_sq=sum_dot(mask, jnp.sum(jnp.square(released), axis=-1)),
+            sum_sq_clipped=sum_dot(mask, jnp.sum(jnp.square(clipped), axis=-1)),
             count=jnp.sum(mask))
         return released, mom
 
@@ -287,7 +288,7 @@ class LDPFedEXPPrivUnit(DPFedAvgPrivUnit):
         """Shard/chunk-local partial sums of this algorithm's release (SUMS, psum-able)."""
         released, mom = self._released_moments(key, deltas, mask, start)
         s_hat = jax.vmap(lambda c: mech.estimate_norm_sq(c, self.pu, self.sc))(released)
-        return mom, {"sum_s_hat": mask @ s_hat}
+        return mom, {"sum_s_hat": sum_dot(mask, s_hat)}
 
     def apply_from_moments(self, key, w, moments, state):
         """Server update from the globally reduced moments (replicated math)."""
@@ -444,7 +445,7 @@ class CDPFedEXPAdaptiveClip(ServerAlgorithm):
         mom = partial_clip_moments(deltas, state.clip, None,
                                    weight_mask=mask, backend=self.backend)
         norms = jnp.linalg.norm(deltas, axis=-1)
-        below = mask @ (norms <= state.clip).astype(jnp.float32)
+        below = sum_dot(mask, (norms <= state.clip).astype(jnp.float32))
         return mom, {"count_below": below}
 
     def apply_from_moments(self, key, w, moments, state):

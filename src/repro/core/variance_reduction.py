@@ -43,6 +43,7 @@ from repro.core.aggregation import (
     RoundMoments,
     global_client_indices,
     materialize_ldp_noise,
+    sum_dot,
 )
 from repro.core.algorithm import RoundAux, ServerAlgorithm
 from repro.core.clipping import clip_batch
@@ -217,13 +218,13 @@ class DPScaffoldServer(ServerAlgorithm):
             rel_dc = dc_clip + materialize_ldp_noise(
                 k_dc, m_local, d, std * vs, deltas.dtype, start=start)
         mom = RoundMoments(
-            sum_c=mask @ rel_dy,
-            sum_sq=mask @ jnp.sum(jnp.square(rel_dy), axis=-1),
-            sum_sq_clipped=mask @ jnp.sum(jnp.square(dy_clip), axis=-1),
+            sum_c=sum_dot(mask, rel_dy),
+            sum_sq=sum_dot(mask, jnp.sum(jnp.square(rel_dy), axis=-1)),
+            sum_sq_clipped=sum_dot(mask, jnp.sum(jnp.square(dy_clip), axis=-1)),
             count=jnp.sum(mask))
         cis_add = jnp.zeros((self.num_clients, d), deltas.dtype) \
             .at[gidx].add(dc_clip * mask[:, None], mode="drop")
-        return mom, {"sum_dc": mask @ rel_dc, "cis_add": cis_add}
+        return mom, {"sum_dc": sum_dot(mask, rel_dc), "cis_add": cis_add}
 
     def apply_from_moments(self, key, w, moments, state):
         """Replicated server update from the psummed two-release moments;

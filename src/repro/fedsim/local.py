@@ -159,12 +159,9 @@ def local_update_spec(loss_fn: Callable, w0, client_batch, key: jax.Array,
     # per epoch (vmapped), then one (steps, b, ...) gather per leaf, and the
     # training scan consumes the pre-gathered minibatches as plain xs.  This
     # keeps fold_in/permutation/gather out of the grad-bearing scan body —
-    # one O(n log n) shuffle per epoch instead of per minibatch, and it is
-    # the formulation that compiles correctly inside vmap-under-shard_map
-    # with a downstream psum (gather+grad inside the scan body miscompiled
-    # per-client randomness on forced-host-device meshes, jax 0.4.37 —
-    # tests/test_local.py pins the sharded == single-device equivalence
-    # this guards).  Cost: epochs extra copies of each client's sample set.
+    # one O(n log n) shuffle per epoch instead of per minibatch
+    # (tests/test_local.py pins sharded == single-device for this path).
+    # Cost: epochs extra copies of each client's sample set.
     perms = jax.vmap(lambda e: jax.random.permutation(
         jax.random.fold_in(key, e), n))(jnp.arange(spec.epochs, dtype=jnp.int32))
     idxs = perms[:, : n_batches * b].reshape(spec.epochs * n_batches, b)

@@ -76,7 +76,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.experimental import io_callback
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.fedexp import ServerAlgorithm, clamp_moment_counts, set_moment_count
@@ -620,11 +619,11 @@ def _build_sharded_stream_chunk_fn(algorithm: ServerAlgorithm, local_fn,
                           fault, tap_ctx)
         return jax.lax.scan(body, carry, (keys, ts), unroll=min(unroll, len(ts)))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         chunk, mesh=mesh,
         in_specs=(P(), P(), P(), batch_specs, mask_spec, P()),
         out_specs=P(),
-        check_rep=False)  # psum-then-replicated-update, as the dense engine
+        check_vma=False)  # psum-then-replicated-update, as the dense engine
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
 
@@ -844,11 +843,11 @@ def _build_sharded_gather_stream_chunk_fn(algorithm: ServerAlgorithm,
                           tap_ctx)
         return jax.lax.scan(body, carry, (keys, ts), unroll=min(unroll, len(ts)))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         chunk, mesh=mesh,
         in_specs=(P(), P(), P(), batch_specs, mask_spec, P()),
         out_specs=P(),
-        check_rep=False)  # psum-then-replicated-update, as the dense engine
+        check_vma=False)  # psum-then-replicated-update, as the dense engine
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
 
@@ -1249,11 +1248,11 @@ def _build_sharded_chunk_fn(algorithm: ServerAlgorithm, local_fn, eval_fn,
                           tap_ctx)
         return jax.lax.scan(body, carry, (keys, ts), unroll=min(unroll, len(ts)))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         chunk, mesh=mesh,
         in_specs=(P(), P(), P(), batch_specs, mask_spec, P()),
         out_specs=P(),
-        check_rep=False)  # psum-then-replicated-update; rep checker can't see it
+        check_vma=False)  # psum-then-replicated-update; vma checker can't see it
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
 
@@ -1339,11 +1338,11 @@ def _build_sharded_batched_run_fn(algorithm: ServerAlgorithm, local_fn, eval_fn,
         return jax.vmap(run_one, in_axes=in_axes)(
             w0, keys, local_batches, mask, eta_l, ts)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         batched, mesh=mesh,
         in_specs=(P(), P(), batch_specs, mask_spec, P(), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(sharded)
 
 

@@ -42,6 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.interpret import resolve_interpret
+
 __all__ = ["dp_aggregate_kernel_call", "ldp_noise_kernel_call"]
 
 _EPS = 1e-12
@@ -70,8 +72,13 @@ def _threefry2x32(k0, k1, x0, x1):
 
 
 def _bits_to_unit(bits):
-    """uint32 -> float32 uniform in the OPEN interval (0, 1) (top 24 bits)."""
-    return ((bits >> jnp.uint32(8)).astype(jnp.float32) + 0.5) * jnp.float32(2.0**-24)
+    """uint32 -> float32 uniform in the OPEN interval (0, 1) (top 24 bits).
+
+    The shifted value is below 2**24, so the int32 bitcast is exact; Mosaic
+    has no uint32 -> float32 conversion, only int32 -> float32.
+    """
+    top = jax.lax.bitcast_convert_type(bits >> jnp.uint32(8), jnp.int32)
+    return (top.astype(jnp.float32) + 0.5) * jnp.float32(2.0**-24)
 
 
 def _noise_block(seed, step, shape, *, tpu_prng: bool):
@@ -114,11 +121,12 @@ def _kernel(meta_i_ref, meta_f_ref, u_ref, *refs,
     sq_norms = jnp.sum(u * u, axis=1, keepdims=True)        # (bm, 1)
     scale = jnp.minimum(1.0, clip_norm / jnp.sqrt(jnp.maximum(sq_norms, _EPS)))
     clipped = u * scale
-    sq_clipped = sq_norms[:, 0] * scale[:, 0] ** 2          # (bm,)
+    # per-row quantities stay (bm, 1): Mosaic cannot relayout 1-D row vectors
+    sq_clipped = sq_norms * scale ** 2                      # (bm, 1)
 
     if noise_mode == "operand":
         released = clipped + n_ref[...].astype(jnp.float32)
-        sq_released = jnp.sum(released * released, axis=1)
+        sq_released = jnp.sum(released * released, axis=1, keepdims=True)
     elif noise_mode == "fused":
         # Padded rows/cols must draw ZERO noise: the wrapper pads u with
         # zeros, which clip to zero, but generated noise would otherwise
@@ -129,17 +137,19 @@ def _kernel(meta_i_ref, meta_f_ref, u_ref, *refs,
         noise = jnp.where(valid, sigma * _noise_block(seed, step, (bm, d),
                                                       tpu_prng=tpu_prng), 0.0)
         released = clipped + noise
-        sq_released = jnp.sum(released * released, axis=1)
+        sq_released = jnp.sum(released * released, axis=1, keepdims=True)
     else:
         released = clipped
         sq_released = sq_clipped
 
     ones = jnp.ones((1, bm), jnp.float32)
+    # HIGHEST: at default precision the TPU MXU multiplies f32 in bf16
     part_sum = jax.lax.dot_general(                         # (1, d) column sum
         ones, released, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    part_sq_rel = jnp.sum(sq_released)[None, None]          # (1, 1)
-    part_sq_clip = jnp.sum(sq_clipped)[None, None]
+    part_sq_rel = jnp.sum(sq_released, keepdims=True)       # (1, 1)
+    part_sq_clip = jnp.sum(sq_clipped, keepdims=True)
 
     @pl.when(step == 0)
     def _init():
@@ -164,7 +174,7 @@ def dp_aggregate_kernel_call(
     m_true: int | None = None,
     d_true: int | None = None,
     block_m: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Invoke the fused kernel.  Expects M % block_m == 0 and d % 128 == 0
     (the ops.py wrapper pads).  ``noise_seed`` (int32 scalar) switches on
@@ -173,6 +183,7 @@ def dp_aggregate_kernel_call(
     (sum_released, sum_sq_released, sum_sq_clipped)."""
     m, d = updates.shape
     assert m % block_m == 0, (m, block_m)
+    interpret = resolve_interpret(interpret)
     if noise is not None and noise_seed is not None:
         raise ValueError("materialized noise and in-kernel noise are exclusive")
     noise_mode = "operand" if noise is not None else (
@@ -237,11 +248,12 @@ def ldp_noise_kernel_call(
     noise_sigma,
     *,
     block_m: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Materialize the exact noise the fused kernel would draw (test oracle;
     shapes must already satisfy the kernel tiling contract)."""
     assert m % block_m == 0, (m, block_m)
+    interpret = resolve_interpret(interpret)
     meta_i = jnp.stack([jnp.asarray(noise_seed, jnp.int32),
                         jnp.asarray(m, jnp.int32), jnp.asarray(d, jnp.int32)])
     meta_f = jnp.asarray(noise_sigma, jnp.float32)[None]

@@ -19,6 +19,7 @@ from repro.kernels.dp_aggregate.kernel import (
     ldp_noise_kernel_call,
 )
 from repro.kernels.dp_aggregate.ref import dp_aggregate_ref
+from repro.kernels.interpret import resolve_interpret
 
 __all__ = ["dp_aggregate", "dp_aggregate_sums", "dp_aggregate_sums_chunked",
            "generate_ldp_noise", "pick_block_m"]
@@ -54,8 +55,7 @@ def pick_block_m(m: int, d_padded: int, interpret: bool) -> int:
 def _resolve_defaults(m: int, d: int, interpret: bool | None,
                       block_m: int | None) -> tuple[bool, int]:
     """One home for the backend/tiling defaults every entry point shares."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if block_m is None:
         block_m = pick_block_m(m, -(-d // 128) * 128, interpret)
     return interpret, block_m

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.interpret import resolve_interpret
+
 __all__ = ["flash_attention_kernel_call"]
 
 _NEG_INF = -1e30
@@ -89,7 +91,7 @@ def flash_attention_kernel_call(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q: (B, Hq, Sq, Dh); k, v: (B, Hkv, Skv, Dh), Sq/Skv multiples of blocks.
 
@@ -123,6 +125,6 @@ def flash_attention_kernel_call(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q.reshape(b * hq, sq, dh), k.reshape(b * hkv, skv, dh), v.reshape(b * hkv, skv, dh))
     return out.reshape(b, hq, sq, dh)

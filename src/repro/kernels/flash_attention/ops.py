@@ -27,7 +27,7 @@ def flash_attention(
     window: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Blockwise attention; q (B, Hq, Sq, Dh), k/v (B, Hkv, Skv, Dh)."""
     sq, skv, dh = q.shape[2], k.shape[2], q.shape[3]

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.interpret import resolve_interpret
+
 __all__ = ["ssd_scan_kernel_call"]
 
 
@@ -81,7 +83,7 @@ def ssd_scan_kernel_call(
     cmat: jax.Array,    # (B, S, N)   output projections
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns y (B, S, H, P). S must be a multiple of ``chunk``."""
     b, s, h, p = x.shape
@@ -103,5 +105,5 @@ def ssd_scan_kernel_call(
         out_specs=pl.BlockSpec((1, chunk, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, a, bmat, cmat)

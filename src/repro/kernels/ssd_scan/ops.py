@@ -12,7 +12,8 @@ __all__ = ["ssd_scan"]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, a, bmat, cmat, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan(x, dt, a, bmat, cmat, *, chunk: int = 128,
+             interpret: bool | None = None):
     """Chunked SSD scan; pads the sequence with dt=0 steps (exact no-ops)."""
     s = x.shape[1]
     c = min(chunk, max(8, s))

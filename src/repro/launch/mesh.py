@@ -7,6 +7,7 @@ tests and benches must keep seeing 1 device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "make_client_mesh",
            "auto_shard_count", "auto_chunk_clients", "client_shard_spec"]
@@ -20,16 +21,23 @@ __all__ = ["make_production_mesh", "make_test_mesh", "make_client_mesh",
 MIN_CLIENTS_PER_SHARD = 24
 
 
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with ``Auto`` axes: the engines place arrays with
+    ``shard_map`` specs and ``with_sharding_constraint``, which refuse the
+    ``Explicit`` axes ``make_mesh`` builds by default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips with a leading pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many (CPU) devices exist — for unit tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_client_mesh(n_shards: int | None = None, *, axis: str = "clients"):
@@ -41,7 +49,7 @@ def make_client_mesh(n_shards: int | None = None, *, axis: str = "clients"):
         XLA_FLAGS=--xla_force_host_platform_device_count=8
     """
     n = n_shards if n_shards is not None else len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return _auto_mesh((n,), (axis,))
 
 
 def auto_shard_count(num_clients: int, *, n_devices: int | None = None,
@@ -66,14 +74,19 @@ def device_memory_budget(*, fraction: float = 0.25,
     backend exposes it (GPU/TPU) and budgets ``fraction`` of it — the rest
     stays free for the model, optimizer state, moments, and XLA temporaries.
     CPU backends report no limit; the documented fallback is 4 GiB, matching
-    the host-RAM assumption of the docs/scaling.md sizing table.
+    the host-RAM assumption of the docs/scaling.md sizing table.  A TPU that
+    reports no limit is an error: guessing would size chunks for the wrong
+    device.
     """
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-    except Exception:
-        limit = 0
-    return int((limit if limit > 0 else fallback_bytes) * fraction)
+    device = jax.devices()[0]
+    limit = int((device.memory_stats() or {}).get("bytes_limit", 0))
+    if limit <= 0:
+        if device.platform == "tpu":
+            raise RuntimeError(
+                f"{device.device_kind} reports no memory_stats()['bytes_limit']"
+                "; pass budget_bytes= explicitly")
+        limit = fallback_bytes
+    return int(limit * fraction)
 
 
 def auto_chunk_clients(dim: int, client_bytes: int = 0, *,

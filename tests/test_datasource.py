@@ -290,6 +290,25 @@ class TestAutoChunk:
         with pytest.raises(ValueError, match="chunk_clients=1"):
             auto_chunk_clients(dim=10**6, client_bytes=0, budget_bytes=1024)
 
+    @pytest.mark.parametrize("platform,stats,want", [
+        ("cpu", None, 1 << 30),                   # no limit: 4 GiB fallback
+        ("tpu", {"bytes_limit": 16 << 30}, 4 << 30),
+        ("tpu", {}, RuntimeError),                # a TPU must report its limit
+    ])
+    def test_device_memory_budget(self, monkeypatch, platform, stats, want):
+        import types
+
+        from repro.launch import mesh as mesh_mod
+
+        device = types.SimpleNamespace(platform=platform, device_kind="test",
+                                       memory_stats=lambda: stats)
+        monkeypatch.setattr(mesh_mod.jax, "devices", lambda: [device])
+        if want is RuntimeError:
+            with pytest.raises(RuntimeError, match="bytes_limit"):
+                mesh_mod.device_memory_budget()
+        else:
+            assert mesh_mod.device_memory_budget() == want
+
     def test_spec_validates_auto_literal(self):
         assert StreamSpec(chunk_clients="auto").is_auto
         with pytest.raises(ValueError, match="auto"):

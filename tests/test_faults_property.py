@@ -76,8 +76,9 @@ def garbage_rows(draw, max_m=12, max_d=16):
     m = draw(st.integers(2, max_m))
     d = draw(st.integers(2, max_d))
     seed = draw(st.integers(0, 2**31 - 1))
-    base = np.asarray(jax.random.normal(jax.random.PRNGKey(seed), (m, d)),
-                      dtype=np.float32)
+    # a writable copy: np.asarray of a jax array is a read-only view
+    base = np.array(jax.random.normal(jax.random.PRNGKey(seed), (m, d)),
+                    dtype=np.float32)
     poison = draw(st.lists(
         st.tuples(st.integers(0, m - 1), st.integers(0, d - 1),
                   st.sampled_from([np.nan, np.inf, -np.inf, 1e38])),

@@ -11,6 +11,7 @@ from repro import optim
 from repro.configs import ARCHS, SHAPES, FederatedConfig, reduced
 from repro.launch import specs as specs_mod
 from repro.launch.hlo_cost import hlo_cost, parse_hlo
+from repro.launch.mesh import make_test_mesh
 from repro.launch.rules import count_params, is_giant, make_rules, safe_pspec
 from repro.launch.serve import ServeEngine
 from repro.models.transformer import DecoderLM
@@ -54,7 +55,7 @@ class TestRules:
             assert lo < n < hi, (name, n)
 
     def test_make_rules_modes(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_test_mesh()
         cfg = ARCHS["gemma-2b"]
         r_train = make_rules(cfg, mesh, mode="train", num_params=2.5e9)
         assert r_train["clients"] == "data"
@@ -66,7 +67,7 @@ class TestRules:
 
 class TestSpecs:
     def test_train_specs_shapes(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_test_mesh()
         cfg = ARCHS["gemma-2b"]
         fed = FederatedConfig(local_steps=2)
         rules = make_rules(cfg, mesh, mode="train", num_params=2.5e9)
@@ -76,7 +77,7 @@ class TestSpecs:
         assert shapes["tokens"].dtype == jnp.int32
 
     def test_decode_specs_cache(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_test_mesh()
         cfg = reduced(ARCHS["mamba2-2.7b"])
         model = DecoderLM(cfg)
         rules = make_rules(cfg, mesh, mode="serve", num_params=1e8)
@@ -158,6 +159,30 @@ class TestCheckpoint:
         ckpt.save_checkpoint(d, 3, {"w": jnp.ones(2)})
         ckpt.save_checkpoint(d, 11, {"w": jnp.ones(2)})
         assert ckpt.latest_step(d) == 11
+
+
+class TestCompileCache:
+    @pytest.mark.parametrize("env", [None, "/elsewhere/cache"])
+    def test_cache_dir(self, monkeypatch, env):
+        """The environment's directory wins and nothing is set; otherwise the
+        cache goes to the fixed, gitignored ``.jax_cache`` in the checkout.
+        ``jax.config.update`` is recorded, not called: tests keep no cache."""
+        from repro.launch import compile_cache
+
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+        updates = []
+        monkeypatch.setattr(compile_cache.jax.config, "update",
+                            lambda *a: updates.append(a))
+        used = compile_cache.use_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        if env is None:
+            assert used == os.path.join(root, ".jax_cache")
+            assert updates == [("jax_compilation_cache_dir", used)]
+        else:
+            assert used == env and updates == []
 
 
 class TestHloCost:

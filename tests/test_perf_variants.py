@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import ARCHS, reduced
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.launch.mesh import make_test_mesh
 from repro.models.attention import blockwise_attention, chunked_attention
 from repro.models.moe import moe_apply, moe_defs
 from repro.models.sharding import AXIS_SIZES_KEY, axis_rules
@@ -61,7 +62,7 @@ class TestGroupLocalMoE:
         # mesh satisfies every constraint trivially, so this exercises the
         # grouped dispatch MATH against the ungrouped path.
         rules = {"batch": "data", AXIS_SIZES_KEY: {"data": 4, "model": 1}}
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_test_mesh()
         with mesh, axis_rules(rules):
             y4, aux4 = jax.jit(lambda p, xx: moe_apply(p, xx, cfg))(params, x)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y4), rtol=2e-4, atol=2e-4)
@@ -79,7 +80,7 @@ class TestGroupLocalMoE:
         cfg, params = self._setup()
         x = jax.random.normal(jax.random.PRNGKey(3), (3, 8, cfg.d_model))
         rules = {"batch": "data", AXIS_SIZES_KEY: {"data": 2, "model": 1}}
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_test_mesh()
         with mesh, axis_rules(rules):
             y, _ = jax.jit(lambda p, xx: moe_apply(p, xx, cfg))(params, x)
         assert y.shape == (3, 8, cfg.d_model)

@@ -597,8 +597,10 @@ if HAS_HYPOTHESIS:
             assert rep.eps_numerical == pytest.approx(max(eps), rel=1e-6)
 
         @PROP
-        @given(eps=st.lists(st.floats(0.2, 8.0), min_size=2, max_size=12,
-                            unique=True))
+        # budgets on a 1e-3 grid: two a float ULP apart (0.2 and
+        # 0.20000000000000004) calibrate to sigmas that round to one double
+        @given(eps=st.lists(st.floats(0.2, 8.0).map(lambda e: round(e, 3)),
+                            min_size=2, max_size=12, unique=True))
         def test_sigma_calibration_is_antitone(self, eps):
             """A bigger budget buys a smaller sigma, strictly."""
             sigmas = per_client_sigmas(tuple(sorted(eps)), DELTA, 0.3)

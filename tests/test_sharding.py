@@ -104,7 +104,11 @@ class TestShardedEquivalence:
             pytest.skip("reduction order only preserved on a 1-device mesh")
         r1 = _run(problem, name)
         r2 = _run(problem, name, mesh=make_client_mesh(1))
-        np.testing.assert_array_equal(np.asarray(r1.final_w), np.asarray(r2.final_w))
+        # fedavg's unclipped sums: XLA:CPU (jax 0.9) fuses the shard_map
+        # program's mask dot differently, 1 f32 ULP (6e-8) at |w| ~ 0.5
+        atol = 1e-7 if name == "fedavg" else 0
+        np.testing.assert_allclose(np.asarray(r1.final_w), np.asarray(r2.final_w),
+                                   rtol=0, atol=atol)
         np.testing.assert_array_equal(np.asarray(r1.eta_history),
                                       np.asarray(r2.eta_history))
 
