@@ -24,11 +24,11 @@ compiled ``dp_aggregate`` kernel.  It checks:
       ``tools/check_telemetry.py`` accepts the stream.
 
 ``--chips 4`` runs only the sharded phase: ``cdp-fedexp`` and
-``ldp-fedexp-gauss`` over a 4-chip ``clients`` mesh, each against the same
-session on one chip with ``engine="stream"``, in blocks of one shard's
-clients.  (The dense LDP release draws its noise from the hardware PRNG;
-the sharded and stream releases draw per-client Threefry rows, so only
-those two are the same run.)
+``ldp-fedexp-gauss`` over a 4-chip ``clients`` mesh, one round each at
+default precision, against the same session on one chip (for LDP on the
+stream engine: the dense LDP release draws its noise from the hardware
+PRNG, the sharded and stream releases draw per-client Threefry rows) and
+against one chip streamed in blocks of one shard's clients.
 
 Details go to earlier lines.  The last line of stdout is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -63,33 +63,34 @@ ALGORITHM = {"cdp": "cdp-fedexp", "ldp-gauss": "ldp-fedexp-gauss"}
 KERNEL_RTOL = 1e-5
 # Sample mean and std of N standard normals: 6 standard errors.
 NOISE_SIGMAS = 6.0
-# (b) "auto" and "jnp" sum the same clipped f32 rows in different orders, so
-# round 1, which starts both runs from the same weights, differs only by f32
-# rounding of those sums: ~1e-7 relative, which eta's bias subtraction may
-# grow 10-100x.  Had the MXU rounded a product to bf16 (2**-9 ~ 2e-3), eta
-# would move by far more than the bar.
-SESSION_ETA1_RTOL = 1e-4
-# Later rounds start from weights that differ by that rounding, and on the
-# TPU the CNN's local training multiplies in bf16 at default precision, where
-# a 1e-7 weight difference flips roundings.  Over 3 rounds a v5e measured
-# eta 1.8e-3 relative apart and final weights 1.6e-3 of the distance trained
-# (the interpreted kernel on CPU: 6e-5 and 7e-4).  The bars leave 5x.
-SESSION_ETA_RTOL = 1e-2
-SESSION_W_RTOL = 1e-2
-# --chips 4: tests/test_sharding.py's bars for sharded vs one device.  They
-# hold where only the reduction order differs, so the comparison runs one
-# round, both runs multiply in f32 (SHARD_PRECISION), and the one-chip run
-# streams the cohort in blocks of one shard's clients, so that local training
-# runs the same shapes on both sides.  XLA tiles the CNN's convolutions by
-# batch size, and tau local steps amplify the rounding that tiling moves:
-# on a v5e, one round of cdp-fedexp on the one-chip scan engine (1000
-# clients in one batch) against 4 chips (250 each) differed by 1.5e-3 in eta
-# and 6.8e-3 in the weights at default precision, and by 1.5e-4 relative in
-# eta and 5.4e-4 in the weights at "highest".
+# (b) "auto" and "jnp" sum the same clipped f32 rows in different orders.
+# Round 1 starts both runs from one w0, so eta differs by f32 rounding only;
+# later rounds start from weights that differ by it, and local training
+# amplifies that.  Readings on a v5e, E2 seeds 0-2 (relative eta error in
+# round 1 / over 3 rounds, final weights as a share of the distance trained):
+#   sound, default precision:     <= 1.2e-7 / <= 1.8e-3 / <= 3.0e-3
+#   sound, local training at "highest" (seed 0): 9.8e-8 / 5.5e-4 / 2.5e-4
+#   kernel column sum and sum_dot multiplying in bf16 (seed 0):
+#                                    8.9e-6 / 4.8e-3 / 1.1e-2
+# Each bar lies between the largest sound reading and the bf16 one.  This
+# script runs seed 0, which reads 9.8e-8 / 1.8e-3 / 1.6e-3 on a v5e.
+SESSION_BARS = {"round-1 eta": 1e-6, "eta": 3e-3, "final_w": 5e-3}
+# --chips 4, one round at default precision.  Each shard trains its 250
+# clients as one vmapped batch, the one-chip session all 1000 at once, and
+# the batch shape alone moves local training's rounding: on one v5e, the
+# same session streamed in 250-client blocks differs from it by (eta,
+# final weights as above) cdp 4.9e-4..1.1e-2 / 3.8e-3..3.0e-2 and ldp
+# 0..8.4e-4 / 4.1e-4..4.7e-3 over seeds 0-2, while the scan and stream
+# engines on one 1000-client block agree exactly.  So 4 chips are held to
+#   - tests/test_sharding.py's bars against one chip streamed in blocks of
+#     one shard's clients (same shapes; only the psum order differs), and
+#   - SHARD_SESSION_BARS against the one-chip session, 3-4x the largest
+#     block-size reading; a shard's moments lost or counted twice would move
+#     the round's update by about a quarter.
 SHARD_W_TOL = dict(rtol=1e-5, atol=1e-5)
 SHARD_ETA_TOL = dict(rtol=1e-4, atol=1e-5)
+SHARD_SESSION_BARS = {"eta": 5e-2, "final_w": 1e-1}
 SHARD_ROUNDS = 1
-SHARD_PRECISION = "highest"
 
 
 def log(msg: str) -> None:
@@ -237,15 +238,24 @@ def finite_failures(label: str, result) -> list[str]:
             and not np.all(np.isfinite(np.asarray(getattr(result, f))))]
 
 
-def round_program_text(session) -> str:
-    """StableHLO of the compiled round-loop chunk ``session.run`` executes."""
-    fn, batches, extra = session._chunk_callable(session._donate())
-    w = session._w0
-    carry = (w, session.algorithm.init_state(w),
-             jnp.zeros((session._tail_n(),) + w.shape, w.dtype))
-    ts = jnp.arange(session.train.rounds, dtype=jnp.int32)
-    return fn.lower(carry, run_key(), ts, batches, *extra,
-                    jnp.float32(session.train.eta_l)).as_text()
+def gaps(ref, other, w0) -> dict[str, float]:
+    """How far ``other`` ran from ``ref``: relative eta error in round 1 and
+    over all rounds, and the largest final-weight difference as a share of
+    the distance ``ref`` trained from ``w0``."""
+    a = np.asarray(ref.eta_history, np.float64)
+    rel = np.abs(np.asarray(other.eta_history, np.float64) - a) / np.abs(a)
+    w_ref = np.asarray(ref.final_w, np.float64)
+    trained = np.max(np.abs(w_ref - np.asarray(w0, np.float64)))
+    w_diff = np.max(np.abs(np.asarray(other.final_w, np.float64) - w_ref))
+    return {"round-1 eta": float(rel[0]), "eta": float(np.max(rel)),
+            "final_w": float(w_diff / trained)}
+
+
+def gap_failures(label: str, measured: dict, bars: dict) -> list[str]:
+    log(f"  {label}: " + ", ".join(f"{k} {measured[k]:.3e} (bar {bars[k]})"
+                                     for k in bars))
+    return [f"{label}: {k} {measured[k]:.3e} > {bars[k]}"
+            for k in bars if not measured[k] <= bars[k]]
 
 
 def summarize(label: str, result, seconds: float) -> None:
@@ -274,7 +284,7 @@ def check_sessions(problems) -> list[str]:
         if not np.array_equal(np.asarray(first.final_w),
                               np.asarray(again.final_w)):
             failures.append(f"{name}: two runs with one key differ")
-        has_kernel = "tpu_custom_call" in round_program_text(session)
+        has_kernel = "tpu_custom_call" in session.lower(run_key()).as_text()
         log(f"  (c) {name}: round program holds tpu_custom_call: {has_kernel}")
         if not has_kernel:
             failures.append(f"(c) {name}: no tpu_custom_call in the round "
@@ -282,30 +292,14 @@ def check_sessions(problems) -> list[str]:
         results[setting] = (session, again)
 
     # (b) the kernel path against the jnp path
-    session, auto = results["cdp"]
+    auto = results["cdp"][1]
     jnp_run, t_jnp = timed_run(make_session(problems["cdp"], "cdp",
                                             backend="jnp"))
     summarize("cdp-fedexp (jnp)", jnp_run, t_jnp)
     failures += finite_failures("cdp-fedexp (jnp)", jnp_run)
-    eta_errs = (np.abs(np.asarray(auto.eta_history, np.float64)
-                       - np.asarray(jnp_run.eta_history, np.float64))
-                / np.abs(np.asarray(jnp_run.eta_history, np.float64)))
-    eta1_err, eta_err = float(eta_errs[0]), float(np.max(eta_errs))
-    trained = float(np.max(np.abs(np.asarray(jnp_run.final_w)
-                                  - np.asarray(session._w0))))
-    w_err = float(np.max(np.abs(np.asarray(auto.final_w)
-                                - np.asarray(jnp_run.final_w)))) / trained
-    log(f"  (b) cdp-fedexp auto vs jnp: round-1 eta rel err {eta1_err:.3e} "
-        f"(bar {SESSION_ETA1_RTOL}), eta rel err {eta_err:.3e} (bar "
-        f"{SESSION_ETA_RTOL}), final_w err {w_err:.3e} of the distance "
-        f"trained {trained:.4f} (bar {SESSION_W_RTOL})")
-    if not eta1_err <= SESSION_ETA1_RTOL:
-        failures.append(f"(b) round-1 eta rel err {eta1_err:.3e} > "
-                        f"{SESSION_ETA1_RTOL}")
-    if not eta_err <= SESSION_ETA_RTOL:
-        failures.append(f"(b) eta rel err {eta_err:.3e} > {SESSION_ETA_RTOL}")
-    if not w_err <= SESSION_W_RTOL:
-        failures.append(f"(b) final_w err {w_err:.3e} > {SESSION_W_RTOL}")
+    failures += gap_failures(
+        "(b) cdp-fedexp auto vs jnp",
+        gaps(jnp_run, auto, problems["cdp"][0].init_flat), SESSION_BARS)
 
     # (d) telemetry through the ordered io_callback inside the scan
     session, ldp = results["ldp-gauss"]
@@ -349,26 +343,42 @@ def compare(label: str, one, sharded) -> list[str]:
 
 
 def check_sharded(problems, chips: int) -> list[str]:
+    """Each setting on ``chips`` chips against two one-chip sessions: the
+    one users run (cdp: scan; ldp: stream in one block, since the dense LDP
+    release draws other noise), and the same clients streamed in blocks of
+    one shard's clients, which trains the shapes each shard trains."""
     from repro.fedsim import StreamSpec
     from repro.launch.mesh import make_client_mesh
 
     mesh = make_client_mesh(chips)
-    per_shard = StreamSpec(chunk_clients=CLIENTS // chips)
+    block = CLIENTS // chips
     failures = []
     for setting in SETTINGS:
-        name = ALGORITHM[setting]
-        with jax.default_matmul_precision(SHARD_PRECISION):
-            one, t_one = timed_run(make_session(
-                problems[setting], setting, engine="stream", stream=per_shard,
-                rounds=SHARD_ROUNDS))
-            sharded, t_sharded = timed_run(make_session(
-                problems[setting], setting, mesh=mesh, rounds=SHARD_ROUNDS))
-        summarize(f"{name} one chip (stream, {per_shard.chunk_clients} "
-                  "clients per block)", one, t_one)
-        summarize(f"{name} {chips} chips (scan)", sharded, t_sharded)
-        failures += finite_failures(f"{name} one chip", one)
-        failures += finite_failures(f"{name} {chips} chips", sharded)
-        failures += compare(f"{name} {chips} chips vs one", one, sharded)
+        name, problem = ALGORITHM[setting], problems[setting]
+        whole = (dict(engine="scan") if setting == "cdp" else
+                 dict(engine="stream", stream=StreamSpec(chunk_clients=CLIENTS)))
+        runs = {
+            "one chip": make_session(problem, setting, rounds=SHARD_ROUNDS,
+                                     **whole),
+            f"one chip, {block}-client blocks": make_session(
+                problem, setting, engine="stream", rounds=SHARD_ROUNDS,
+                stream=StreamSpec(chunk_clients=block)),
+            f"{chips} chips": make_session(problem, setting, mesh=mesh,
+                                           rounds=SHARD_ROUNDS),
+        }
+        for label, session in runs.items():
+            runs[label], seconds = timed_run(session)
+            summarize(f"{name} {label}", runs[label], seconds)
+            failures += finite_failures(f"{name} {label}", runs[label])
+        one, blocks, sharded = runs.values()
+        w0 = problem[0].init_flat
+        failures += compare(f"{name} {chips} chips vs one chip in "
+                            f"{block}-client blocks", blocks, sharded)
+        failures += gap_failures(f"{name} {chips} chips vs one chip",
+                                 gaps(one, sharded, w0), SHARD_SESSION_BARS)
+        log(f"  {name} one chip in {block}-client blocks vs one chip "
+            "(block size alone): " + ", ".join(
+                f"{k} {v:.3e}" for k, v in gaps(one, blocks, w0).items()))
     return failures
 
 

@@ -1012,6 +1012,32 @@ class FederatedSession:
             fault_round=fault_round,
         )
 
+    def _initial_carry(self, donate: bool):
+        """Round-0 carry (w, algorithm state, tail window)."""
+        # Donation would consume the caller's w0 buffer; hand a copy.
+        w = jnp.array(self._w0, copy=True) if donate else jnp.asarray(self._w0)
+        return (w, self.algorithm.init_state(w),
+                jnp.zeros((self._tail_n(),) + w.shape, w.dtype))
+
+    def lower(self, key: jax.Array) -> jax.stages.Lowered:
+        """Lower, without running, the first compiled round program that an
+        untracked ``run(key)`` executes (the chunk starting at round 0), e.g.
+        to read its HLO.  The eager engine and host-resident sources run no
+        single program and raise."""
+        if self.engine.engine == "eager" or self._source is not None:
+            raise ValueError("lower() needs a compiled scan or stream "
+                             "engine on device-resident data")
+        t = self.train
+        donate = self._donate()
+        carry = self._initial_carry(donate)
+        if self._watchdog:
+            carry = carry + (jnp.int32(-1),)
+        fn, batches, extra = self._chunk_callable(donate)
+        start, stop = self._chunk_bounds(0, t.rounds, self.engine.chunk_rounds,
+                                         None, self.telemetry.profile_rounds)[0]
+        return fn.lower(carry, key, jnp.arange(start, stop, dtype=jnp.int32),
+                        batches, *extra, jnp.float32(t.eta_l))
+
     def _run_scan(self, key, *, start: int, carry, hist,
                   checkpoint_dir: str | None,
                   checkpoint_every: int | None,
@@ -1022,11 +1048,7 @@ class FederatedSession:
         watchdog = self._watchdog
         donate = self._donate()
         if carry is None:
-            # Donation would consume the caller's w0 buffer; hand a copy.
-            w = (jnp.array(self._w0, copy=True) if donate
-                 else jnp.asarray(self._w0))
-            carry = (w, self.algorithm.init_state(w),
-                     jnp.zeros((self._tail_n(),) + w.shape, w.dtype))
+            carry = self._initial_carry(donate)
         if watchdog and len(carry) == 3:
             carry = carry + (jnp.int32(-1),)
         fn, batches, extra = self._chunk_callable(donate, tap=tap)

@@ -597,14 +597,20 @@ if HAS_HYPOTHESIS:
             assert rep.eps_numerical == pytest.approx(max(eps), rel=1e-6)
 
         @PROP
-        # budgets on a 1e-3 grid: two a float ULP apart (0.2 and
-        # 0.20000000000000004) calibrate to sigmas that round to one double
-        @given(eps=st.lists(st.floats(0.2, 8.0).map(lambda e: round(e, 3)),
-                            min_size=2, max_size=12, unique=True))
+        @given(eps=st.lists(st.floats(0.2, 8.0), min_size=2, max_size=12,
+                            unique=True))
         def test_sigma_calibration_is_antitone(self, eps):
-            """A bigger budget buys a smaller sigma, strictly."""
-            sigmas = per_client_sigmas(tuple(sorted(eps)), DELTA, 0.3)
-            assert all(a > b for a, b in zip(sigmas, sigmas[1:]))
+            """A bigger budget buys a smaller sigma: strictly where two
+            budgets differ by more than the calibration resolves (1e-6
+            relative, as above); budgets a float ULP apart, such as 0.2 and
+            0.20000000000000004, may calibrate to one sigma."""
+            eps = sorted(eps)
+            sigmas = per_client_sigmas(tuple(eps), DELTA, 0.3)
+            for e0, e1, s0, s1 in zip(eps, eps[1:], sigmas, sigmas[1:]):
+                if e1 > e0 * (1.0 + 1e-6):
+                    assert s1 < s0, (e0, e1)
+                else:
+                    assert s1 <= s0, (e0, e1)
 
         @PROP
         @given(sigma=st.floats(0.2, 2.0), rounds=st.integers(2, 10),

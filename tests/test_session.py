@@ -93,6 +93,26 @@ class TestShims:
                                       np.asarray(r_f.eta_history))
 
 
+class TestLower:
+    """``session.lower(key)`` is the first chunk program ``run`` executes."""
+
+    @pytest.mark.parametrize("engine", ["scan", "stream"])
+    def test_lowers_the_first_chunk(self, problem, engine):
+        session = _session(problem, "cdp-fedexp",
+                           engine=EngineSpec(engine=engine, chunk_rounds=2))
+        lowered = session.lower(jax.random.PRNGKey(5))
+        carry, hist = lowered.out_info
+        assert carry[0].shape == (D,)
+        assert [h.shape for h in hist] == [(2,)] * 4   # rounds [0, 2)
+        lowered.compile()
+
+    def test_eager_has_no_program(self, problem):
+        session = _session(problem, "cdp-fedexp",
+                           engine=EngineSpec(engine="eager"))
+        with pytest.raises(ValueError, match="compiled scan or stream"):
+            session.lower(jax.random.PRNGKey(5))
+
+
 class TestSessionReuse:
     def test_repeated_runs_deterministic_and_cached(self, problem):
         sess = _session(problem, "cdp-fedexp")
