@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.aggregation import RoundMoments, global_client_indices
+from repro.telemetry import spans
 
 __all__ = [
     "RoundAux",
@@ -172,7 +173,8 @@ class ServerAlgorithm:
         one ULP apart."""
         start = jax.lax.axis_index(axis_name) * deltas.shape[0]
         moments = self.local_moments(key, w, deltas, mask, start, state)
-        moments = jax.lax.psum(moments, axis_name)
+        with jax.named_scope(spans.PSUM):
+            moments = jax.lax.psum(moments, axis_name)
         if m_total is not None and self.supports_static_count:
             moments = set_moment_count(moments, m_total)
         return self.apply_from_moments(key, w, moments, state)

@@ -77,6 +77,7 @@ from repro.core.algorithm import (
     client_keys,
     set_moment_count,
 )
+from repro.telemetry import spans
 
 __all__ = [
     "PrivacyMechanism",
@@ -1312,13 +1313,16 @@ class ComposedAlgorithm(ServerAlgorithm):
             moments = self.local_moments(key, w, raw_deltas, mask, 0, state,
                                          t=t)
             return self.apply_from_moments(key, w, moments, state, t=t)
-        stats, extras = mech_t.release(k_mech, raw_deltas, clip, float(m))
-        if self.step.needs_clip_bits:
-            norms = jnp.linalg.norm(raw_deltas, axis=-1)
-            extras = dict(extras)
-            extras["count_below"] = jnp.sum((norms <= clip).astype(jnp.float32))
-        return self.step.apply(extra, w, stats, extras, mech_t, clip,
-                               float(m), state)
+        with jax.named_scope(spans.RELEASE):
+            stats, extras = mech_t.release(k_mech, raw_deltas, clip, float(m))
+            if self.step.needs_clip_bits:
+                norms = jnp.linalg.norm(raw_deltas, axis=-1)
+                extras = dict(extras)
+                extras["count_below"] = jnp.sum(
+                    (norms <= clip).astype(jnp.float32))
+        with jax.named_scope(spans.SERVER_STEP):
+            return self.step.apply(extra, w, stats, extras, mech_t, clip,
+                                   float(m), state)
 
     def apply_round(self, key, w, raw_deltas, t=None):
         """One dense server round: ``(key, w, (M, d) raw deltas) -> (w_next, RoundAux)``."""
@@ -1338,21 +1342,22 @@ class ComposedAlgorithm(ServerAlgorithm):
         # clip).  For the monolithic-parity names this is the raw key
         # (no-split steps) or a key their mechanisms never read (CDP).
         k_mech, _ = self._split_keys(key)
-        if self.aggregation.is_compressed:
-            plan = self._round_plan(key, deltas.shape[-1])
-            mom, extras = mech_t.moments(
-                k_mech, deltas, mask, start, clip, weights,
-                compress_fn=self.aggregation.compress_fn(plan),
-                compress_row_bound=self._compress_row_bound(clip))
-        else:
-            mom, extras = mech_t.moments(k_mech, deltas, mask, start,
-                                         clip, weights)
-        if self.step.needs_clip_bits:
-            norms = jnp.linalg.norm(deltas, axis=-1)
-            below = (norms <= clip).astype(jnp.float32)
-            extras = dict(extras)
-            extras["count_below"] = (jnp.sum(below) if mask is None
-                                     else sum_dot(mask, below))
+        with jax.named_scope(spans.RELEASE):
+            if self.aggregation.is_compressed:
+                plan = self._round_plan(key, deltas.shape[-1])
+                mom, extras = mech_t.moments(
+                    k_mech, deltas, mask, start, clip, weights,
+                    compress_fn=self.aggregation.compress_fn(plan),
+                    compress_row_bound=self._compress_row_bound(clip))
+            else:
+                mom, extras = mech_t.moments(k_mech, deltas, mask, start,
+                                             clip, weights)
+            if self.step.needs_clip_bits:
+                norms = jnp.linalg.norm(deltas, axis=-1)
+                below = (norms <= clip).astype(jnp.float32)
+                extras = dict(extras)
+                extras["count_below"] = (jnp.sum(below) if mask is None
+                                         else sum_dot(mask, below))
         if self.aggregation.is_weighted:
             # under weighted aggregation mom.count is a weight SUM; the
             # clip-quantile update and any realized-cohort noise need the
@@ -1377,11 +1382,13 @@ class ComposedAlgorithm(ServerAlgorithm):
         if self.aggregation.is_compressed:
             return self._apply_compressed(key, k_mech, extra, w, mom, extras,
                                           clip, m_eff, state, mech_t)
-        stats, more = mech_t.finalize(k_mech, mom, extras, clip, m_eff)
+        with jax.named_scope(spans.RELEASE):
+            stats, more = mech_t.finalize(k_mech, mom, extras, clip, m_eff)
         if more:
             extras = {**extras, **more}
-        return self.step.apply(extra, w, stats, extras, mech_t, clip,
-                               mom.count, state)
+        with jax.named_scope(spans.SERVER_STEP):
+            return self.step.apply(extra, w, stats, extras, mech_t, clip,
+                                   mom.count, state)
 
     def apply_round_sharded(self, key, w, deltas, mask, state, axis_name,
                             m_total=None, t=None):
@@ -1389,7 +1396,8 @@ class ComposedAlgorithm(ServerAlgorithm):
         (the base implementation is otherwise unchanged — DESIGN.md §9)."""
         start = jax.lax.axis_index(axis_name) * deltas.shape[0]
         moments = self.local_moments(key, w, deltas, mask, start, state, t=t)
-        moments = jax.lax.psum(moments, axis_name)
+        with jax.named_scope(spans.PSUM):
+            moments = jax.lax.psum(moments, axis_name)
         if m_total is not None and self.supports_static_count:
             moments = set_moment_count(moments, m_total)
         return self.apply_from_moments(key, w, moments, state, t=t)
@@ -1409,11 +1417,13 @@ class ComposedAlgorithm(ServerAlgorithm):
         inner = self._inner_state(state)
         d = w.shape[-1]
         plan = self._round_plan(key, d)
-        comp_mean = mom.sum_c / mom.count
-        noise = mech_t.compressed_noise(
-            k_mech, comp_mean.shape, clip, m_eff, self.aggregation.sens_factor)
-        if noise is not None:
-            comp_mean = comp_mean + noise
+        with jax.named_scope(spans.RELEASE):
+            comp_mean = mom.sum_c / mom.count
+            noise = mech_t.compressed_noise(k_mech, comp_mean.shape, clip,
+                                            m_eff,
+                                            self.aggregation.sens_factor)
+            if noise is not None:
+                comp_mean = comp_mean + noise
         g = self.aggregation.decompress(comp_mean, plan, d)
         if self.aggregation.uses_error_feedback:
             corrected = g + state.ef
@@ -1426,8 +1436,9 @@ class ComposedAlgorithm(ServerAlgorithm):
                            mean_sq=mom.sum_sq / mom.count,
                            agg_sq=jnp.sum(jnp.square(applied)),
                            mean_sq_clipped=mom.sum_sq_clipped / mom.count)
-        w_next, aux, inner_next = self.step.apply(
-            extra, w, stats, extras, mech_t, clip, mom.count, inner)
+        with jax.named_scope(spans.SERVER_STEP):
+            w_next, aux, inner_next = self.step.apply(
+                extra, w, stats, extras, mech_t, clip, mom.count, inner)
         if ef_next is not None:
             return w_next, aux, CompressionCarry(ef=ef_next, inner=inner_next)
         return w_next, aux, inner_next

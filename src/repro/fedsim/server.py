@@ -83,6 +83,7 @@ from repro.fedsim.faults import apply_faults, fault_masks, gather_fault_rows, re
 from repro.fedsim.local import gather_rows, gather_slots, mask_rows
 from repro.fedsim.specs import CohortSpec, FaultSpec, StreamSpec
 from repro.models.sharding import client_axis_rules, logical_to_pspec
+from repro.telemetry import spans
 
 __all__ = ["RunResult", "run_federated", "run_federated_batched"]
 
@@ -126,11 +127,12 @@ def _eval_metric(eval_fn, eval_every: int, w_next, t):
     """
     if eval_fn is None:
         return jnp.float32(jnp.nan)
-    if eval_every == 1:
-        return eval_fn(w_next)
-    return jax.lax.cond((t + 1) % eval_every == 0,
-                        lambda w: jnp.asarray(eval_fn(w), jnp.float32),
-                        lambda w: jnp.float32(jnp.nan), w_next)
+    with jax.named_scope(spans.EVAL):
+        if eval_every == 1:
+            return eval_fn(w_next)
+        return jax.lax.cond((t + 1) % eval_every == 0,
+                            lambda w: jnp.asarray(eval_fn(w), jnp.float32),
+                            lambda w: jnp.float32(jnp.nan), w_next)
 
 
 def _resolve_sampled_count(moments, cohort: CohortSpec, algorithm):
@@ -200,13 +202,14 @@ def _local_caller(local_fn, fault: FaultSpec | None, tau: int,
 
     def call(w, batches, eta_l, round_key, start, straggler_rows=None,
              opt_state=None):
-        args = (w, batches, eta_l, round_key, start)
-        if straggling:
-            args += (resolve_steps(fault, straggler_rows, tau),)
-        if with_ctx:
-            m_local = jax.tree_util.tree_leaves(batches)[0].shape[0]
-            args += (algorithm.local_context(opt_state, start, m_local),)
-        return local_fn(*args)
+        with jax.named_scope(spans.LOCAL_UPDATE):
+            args = (w, batches, eta_l, round_key, start)
+            if straggling:
+                args += (resolve_steps(fault, straggler_rows, tau),)
+            if with_ctx:
+                m_local = jax.tree_util.tree_leaves(batches)[0].shape[0]
+                args += (algorithm.local_context(opt_state, start, m_local),)
+            return local_fn(*args)
 
     return call
 
@@ -384,7 +387,8 @@ def _sharded_round_step(algorithm, local_fn, eval_fn, axis, m_true,
                           None, opt_state), mask)
             moments = algorithm.local_moments(round_key, w, deltas, mask,
                                               start, opt_state, **tkw)
-            moments = jax.lax.psum(moments, axis)
+            with jax.named_scope(spans.PSUM):
+                moments = jax.lax.psum(moments, axis)
             if injecting:
                 moments = sanitize_moments(moments)
                 moments = _resolve_realized_count(moments, algorithm)
@@ -513,7 +517,8 @@ def _stream_round_step(algorithm, local_fn, eval_fn,
         moments, _ = jax.lax.scan(
             body, acc0, (js, chunk_batches, chunk_mask, fault_grid))
         if axis is not None:
-            moments = jax.lax.psum(moments, axis)
+            with jax.named_scope(spans.PSUM):
+                moments = jax.lax.psum(moments, axis)
         if injecting:
             moments = sanitize_moments(moments)
             moments = _resolve_realized_count(moments, algorithm)
@@ -751,7 +756,8 @@ def _gather_stream_round_step(algorithm, local_fn, eval_fn,
         moments, _ = jax.lax.scan(body, acc0,
                                   (slot_grid, mask_grid, fault_grid))
         if axis is not None:
-            moments = jax.lax.psum(moments, axis)
+            with jax.named_scope(spans.PSUM):
+                moments = jax.lax.psum(moments, axis)
         if injecting:
             moments = sanitize_moments(moments)
             moments = _resolve_realized_count(moments, algorithm)
@@ -1059,33 +1065,34 @@ def _tap_emit(tap_ctx, round_key, t, opt_state, outs, fault_t):
     """
     from repro.telemetry import tap as _tap
 
-    m_true, cohort, fault, axis, clip_fn, sigma_fn = tap_ctx
-    eta, metric, naive, target = outs
-    sampled = cohort is not None and cohort.is_sampled
-    participants = (jnp.sum(cohort.round_mask(round_key, m_true))
-                    if sampled else jnp.float32(m_true))
-    if fault is not None and fault.injects:
-        alive, strag, corr = fault_masks(fault, round_key, m_true)
-        ones = jnp.ones((m_true,), jnp.float32)
-        zeros = jnp.zeros((m_true,), jnp.float32)
-        alive = ones if alive is None else alive
-        strag = zeros if strag is None else strag
-        corr = zeros if corr is None else corr
-        mask = (cohort.round_mask(round_key, m_true) if sampled else ones)
-        realized = jnp.sum(mask * alive * (1.0 - corr))
-        dropped = jnp.sum(mask * (1.0 - alive))
-        stragglers = jnp.sum(mask * alive * strag)
-        corrupt = jnp.sum(mask * alive * corr)
-    else:
-        realized = participants
-        dropped = stragglers = corrupt = jnp.float32(0.0)
-    payload = jnp.stack([
-        jnp.float32(eta), jnp.float32(naive), jnp.float32(target),
-        jnp.float32(metric), clip_fn(opt_state), participants, realized,
-        dropped, stragglers, corrupt, jnp.float32(fault_t), sigma_fn(t)])
-    shard = jnp.int32(0) if axis is None else jax.lax.axis_index(axis)
-    io_callback(_tap.device_emit, None, t, shard, payload,
-                ordered=(axis is None))
+    with jax.named_scope(spans.TAP):
+        m_true, cohort, fault, axis, clip_fn, sigma_fn = tap_ctx
+        eta, metric, naive, target = outs
+        sampled = cohort is not None and cohort.is_sampled
+        participants = (jnp.sum(cohort.round_mask(round_key, m_true))
+                        if sampled else jnp.float32(m_true))
+        if fault is not None and fault.injects:
+            alive, strag, corr = fault_masks(fault, round_key, m_true)
+            ones = jnp.ones((m_true,), jnp.float32)
+            zeros = jnp.zeros((m_true,), jnp.float32)
+            alive = ones if alive is None else alive
+            strag = zeros if strag is None else strag
+            corr = zeros if corr is None else corr
+            mask = (cohort.round_mask(round_key, m_true) if sampled else ones)
+            realized = jnp.sum(mask * alive * (1.0 - corr))
+            dropped = jnp.sum(mask * (1.0 - alive))
+            stragglers = jnp.sum(mask * alive * strag)
+            corrupt = jnp.sum(mask * alive * corr)
+        else:
+            realized = participants
+            dropped = stragglers = corrupt = jnp.float32(0.0)
+        payload = jnp.stack([
+            jnp.float32(eta), jnp.float32(naive), jnp.float32(target),
+            jnp.float32(metric), clip_fn(opt_state), participants, realized,
+            dropped, stragglers, corrupt, jnp.float32(fault_t), sigma_fn(t)])
+        shard = jnp.int32(0) if axis is None else jax.lax.axis_index(axis)
+        io_callback(_tap.device_emit, None, t, shard, payload,
+                    ordered=(axis is None))
 
 
 def _scan_body(step_round, client_batches, eta_l,

@@ -71,7 +71,7 @@ from repro.fedsim.specs import (
     TelemetrySpec,
     TrainSpec,
 )
-from repro.telemetry import NullTracker, Tracker
+from repro.telemetry import NullTracker, Tracker, spans
 from repro.telemetry import tap as _tap_mod
 
 __all__ = ["FederatedSession", "RecoveryPolicy"]
@@ -189,6 +189,8 @@ class FederatedSession:
         # privacy compositions consumed by rolled-back rounds (recovery);
         # privacy_report folds these into the round count
         self._rounds_retried = 0
+        # run()/resume() calls so far: the ``call`` of their profiler spans
+        self._calls = 0
         # test hook: callable (carry, attempt) -> carry applied before the
         # first chunk of each recovery attempt — lets tests inject a
         # TRANSIENT divergence (poison attempt 0 only) so the retried run is
@@ -481,67 +483,70 @@ class FederatedSession:
             w, opt_state, tail = carry
             cols = ([], [], [], [])
             for t_host in np.asarray(ts):
-                t = jnp.int32(int(t_host))
-                rk = jax.random.fold_in(key, t)
-                if gathering:
-                    round_mask = cohort.round_mask(rk, m)
-                    slots, slot_mask, _ = gather_slots(round_mask, grid)
-                    slots_np = np.asarray(jax.device_get(slots))
-                    sgrid = slots.reshape(n_chunks, c)
-                    mgrid = slot_mask.reshape(n_chunks, c)
-                    plan = ((slots_np[j * c:(j + 1) * c], mgrid[j], sgrid[j])
-                            for j in range(n_chunks))
-                else:
-                    round_mask = (cohort.round_mask(rk, m)
-                                  if cohort is not None
-                                  else jnp.ones((m,), jnp.float32))
-                    full = jnp.concatenate(
-                        [round_mask, jnp.zeros((grid - m,), jnp.float32)])
-                    mgrid = full.reshape(n_chunks, c)
-                    plan = ((dense_idx[j], mgrid[j], dense_gidx[j])
-                            for j in range(n_chunks))
+                with jax.profiler.TraceAnnotation(
+                        spans.DISPATCH, call=self._calls,
+                        rounds=f"{t_host}:{t_host + 1}"):
+                    t = jnp.int32(int(t_host))
+                    rk = jax.random.fold_in(key, t)
+                    if gathering:
+                        round_mask = cohort.round_mask(rk, m)
+                        slots, slot_mask, _ = gather_slots(round_mask, grid)
+                        slots_np = np.asarray(jax.device_get(slots))
+                        sgrid = slots.reshape(n_chunks, c)
+                        mgrid = slot_mask.reshape(n_chunks, c)
+                        plan = ((slots_np[j * c:(j + 1) * c], mgrid[j], sgrid[j])
+                                for j in range(n_chunks))
+                    else:
+                        round_mask = (cohort.round_mask(rk, m)
+                                      if cohort is not None
+                                      else jnp.ones((m,), jnp.float32))
+                        full = jnp.concatenate(
+                            [round_mask, jnp.zeros((grid - m,), jnp.float32)])
+                        mgrid = full.reshape(n_chunks, c)
+                        plan = ((dense_idx[j], mgrid[j], dense_gidx[j])
+                                for j in range(n_chunks))
 
-                buf = collections.deque()
+                    buf = collections.deque()
 
-                def stage(plan=plan, buf=buf):
-                    """Fetch + device_put the next planned chunk, if any."""
-                    p = next(plan, None)
-                    if p is None:
-                        return
-                    idx_np, mask_j, gidx_j = p
-                    buf.append((jax.device_put(source.fetch(idx_np)),
-                                mask_j, gidx_j))
+                    def stage(plan=plan, buf=buf):
+                        """Fetch + device_put the next planned chunk, if any."""
+                        p = next(plan, None)
+                        if p is None:
+                            return
+                        idx_np, mask_j, gidx_j = p
+                        buf.append((jax.device_put(source.fetch(idx_np)),
+                                    mask_j, gidx_j))
 
-                for _ in range(depth):
-                    stage()
-                moments = None
-                while buf:
-                    batches_j, mask_j, gidx_j = buf.popleft()
-                    mom = moments_fn(w, opt_state, rk, batches_j, mask_j,
-                                     gidx_j, eta_l, t)
-                    # refill AFTER dispatch: the next fetch/transfer overlaps
-                    # the asynchronously executing chunk program
-                    stage()
-                    moments = (mom if moments is None
-                               else _srv._host_add_moments(moments, mom))
-                clip_val = clip_fn(opt_state) if tap else None
-                w, opt_state, tail, outs = finalize(w, opt_state, tail,
-                                                    rk, t, moments)
-                for col, v in zip(cols, outs):
-                    col.append(v)
-                if tap:
-                    sess = _tap_mod.active()
-                    if sess is not None:
-                        eta, metric, naive, target = outs
-                        part = jnp.sum(round_mask)
-                        payload = np.asarray(jax.device_get(jnp.stack([
-                            jnp.float32(eta), jnp.float32(naive),
-                            jnp.float32(target), jnp.float32(metric),
-                            jnp.float32(clip_val), part, part,
-                            jnp.float32(0.0), jnp.float32(0.0),
-                            jnp.float32(0.0), jnp.float32(-1.0),
-                            sigma_fn(t)])))
-                        sess.emit(int(t_host), 0, payload)
+                    for _ in range(depth):
+                        stage()
+                    moments = None
+                    while buf:
+                        batches_j, mask_j, gidx_j = buf.popleft()
+                        mom = moments_fn(w, opt_state, rk, batches_j, mask_j,
+                                         gidx_j, eta_l, t)
+                        # refill AFTER dispatch: the next fetch/transfer overlaps
+                        # the asynchronously executing chunk program
+                        stage()
+                        moments = (mom if moments is None
+                                   else _srv._host_add_moments(moments, mom))
+                    clip_val = clip_fn(opt_state) if tap else None
+                    w, opt_state, tail, outs = finalize(w, opt_state, tail,
+                                                        rk, t, moments)
+                    for col, v in zip(cols, outs):
+                        col.append(v)
+                    if tap:
+                        sess = _tap_mod.active()
+                        if sess is not None:
+                            eta, metric, naive, target = outs
+                            part = jnp.sum(round_mask)
+                            payload = np.asarray(jax.device_get(jnp.stack([
+                                jnp.float32(eta), jnp.float32(naive),
+                                jnp.float32(target), jnp.float32(metric),
+                                jnp.float32(clip_val), part, part,
+                                jnp.float32(0.0), jnp.float32(0.0),
+                                jnp.float32(0.0), jnp.float32(-1.0),
+                                sigma_fn(t)])))
+                            sess.emit(int(t_host), 0, payload)
             hist = tuple(jnp.stack(col) if col
                          else jnp.zeros((0,), jnp.float32) for col in cols)
             return (w, opt_state, tail), hist
@@ -656,7 +661,7 @@ class FederatedSession:
         return _tap_mod.TapSession(
             tracker, start_round=start_round, ledger_fn=self._ledger_fn(),
             faults_active=self.fault is not None and self.fault.injects,
-            bytes_per_round=self._bytes_per_round())
+            bytes_per_round=self._bytes_per_round(), call=self._calls)
 
     # -- entry points ------------------------------------------------------
 
@@ -683,32 +688,35 @@ class FederatedSession:
         privacy composition reported by ``privacy_report`` (and charge the
         live ledger), and each rollback is logged as a tracker event.
         """
-        self._validate_cohort(self.num_clients)
-        if checkpoint_every is not None and checkpoint_dir is None:
-            raise ValueError("checkpoint_every requires checkpoint_dir "
-                             "(nothing would be saved)")
-        if on_divergence is not None:
-            if not self._watchdog:
-                raise ValueError(
-                    "on_divergence requires FaultSpec(watchdog=True) — "
-                    "without the watchdog a diverged run never trips")
-            if checkpoint_dir is None:
-                raise ValueError("on_divergence requires checkpoint_dir "
-                                 "(rollback needs a checkpoint target)")
-        if not self._tap_on(tracker):
-            return self._run_dispatch(key, checkpoint_dir, checkpoint_every,
-                                      on_divergence, tap=False)
-        _tap_mod.install(self._tap_session(tracker, 0))
-        tracker.start_phase("run", 0)
-        try:
-            return self._run_dispatch(key, checkpoint_dir, checkpoint_every,
-                                      on_divergence, tap=True)
-        finally:
-            # flush every in-flight io_callback BEFORE detaching the session,
-            # so no emission lands after finish()
-            jax.effects_barrier()
-            _tap_mod.uninstall()
-            tracker.finish()
+        self._calls += 1
+        with jax.profiler.TraceAnnotation(spans.RUN, call=self._calls):
+            self._validate_cohort(self.num_clients)
+            if checkpoint_every is not None and checkpoint_dir is None:
+                raise ValueError("checkpoint_every requires checkpoint_dir "
+                                 "(nothing would be saved)")
+            if on_divergence is not None:
+                if not self._watchdog:
+                    raise ValueError(
+                        "on_divergence requires FaultSpec(watchdog=True) — "
+                        "without the watchdog a diverged run never trips")
+                if checkpoint_dir is None:
+                    raise ValueError("on_divergence requires checkpoint_dir "
+                                     "(rollback needs a checkpoint target)")
+            if not self._tap_on(tracker):
+                return self._run_dispatch(key, checkpoint_dir, checkpoint_every,
+                                          on_divergence, tap=False)
+            _tap_mod.install(self._tap_session(tracker, 0))
+            tracker.start_phase("run", 0)
+            try:
+                return self._run_dispatch(key, checkpoint_dir, checkpoint_every,
+                                          on_divergence, tap=True)
+            finally:
+                # flush every in-flight io_callback BEFORE detaching the
+                # session, so no emission lands after finish()
+                with jax.profiler.TraceAnnotation(spans.FLUSH):
+                    jax.effects_barrier()
+                _tap_mod.uninstall()
+                tracker.finish()
 
     def _run_dispatch(self, key, checkpoint_dir, checkpoint_every,
                       on_divergence, *, tap: bool) -> RunResult:
@@ -746,27 +754,30 @@ class FederatedSession:
         duplicate of a round the checkpointed run already emitted; the
         cumulative ledger still counts from round 0.
         """
-        self._validate_cohort(self.num_clients)
-        step, key, carry, hist = self._load(checkpoint_dir)
-        if step > self.train.rounds:
-            raise ValueError(f"checkpoint is at round {step}, past this "
-                             f"session's train.rounds={self.train.rounds}")
-        if step == self.train.rounds:
-            return self._assemble(carry, [hist])
-        if not self._tap_on(tracker):
-            return self._run_scan(key, start=step, carry=carry, hist=[hist],
-                                  checkpoint_dir=checkpoint_dir,
-                                  checkpoint_every=checkpoint_every)
-        _tap_mod.install(self._tap_session(tracker, step))
-        tracker.start_phase("resume", step)
-        try:
-            return self._run_scan(key, start=step, carry=carry, hist=[hist],
-                                  checkpoint_dir=checkpoint_dir,
-                                  checkpoint_every=checkpoint_every, tap=True)
-        finally:
-            jax.effects_barrier()
-            _tap_mod.uninstall()
-            tracker.finish()
+        self._calls += 1
+        with jax.profiler.TraceAnnotation(spans.RUN, call=self._calls):
+            self._validate_cohort(self.num_clients)
+            step, key, carry, hist = self._load(checkpoint_dir)
+            if step > self.train.rounds:
+                raise ValueError(f"checkpoint is at round {step}, past this "
+                                 f"session's train.rounds={self.train.rounds}")
+            if step == self.train.rounds:
+                return self._assemble(carry, [hist])
+            if not self._tap_on(tracker):
+                return self._run_scan(key, start=step, carry=carry, hist=[hist],
+                                      checkpoint_dir=checkpoint_dir,
+                                      checkpoint_every=checkpoint_every)
+            _tap_mod.install(self._tap_session(tracker, step))
+            tracker.start_phase("resume", step)
+            try:
+                return self._run_scan(key, start=step, carry=carry, hist=[hist],
+                                      checkpoint_dir=checkpoint_dir,
+                                      checkpoint_every=checkpoint_every, tap=True)
+            finally:
+                with jax.profiler.TraceAnnotation(spans.FLUSH):
+                    jax.effects_barrier()
+                _tap_mod.uninstall()
+                tracker.finish()
 
     def run_batched(self, keys: jax.Array, *, batched_w0: bool = False,
                     batched_data: bool = False,
@@ -994,23 +1005,24 @@ class FederatedSession:
             for i in range(4))
 
     def _assemble(self, carry, outs) -> RunResult:
-        etas, metrics, naives, targets = self._cat_hist(outs)
-        if len(carry) == 4:  # watchdog carry (§13)
-            w_last, _, tail, fault_t = carry
-            ft = int(jax.device_get(fault_t))
-            fault_round = ft if ft >= 0 else None
-        else:
-            w_last, _, tail = carry
-            fault_round = None
-        return RunResult(
-            final_w=self._restore_params(jnp.mean(tail, axis=0)),
-            last_w=self._restore_params(w_last),
-            eta_history=etas,
-            metric_history=metrics,
-            eta_naive_history=naives,
-            eta_target_history=targets,
-            fault_round=fault_round,
-        )
+        with jax.profiler.TraceAnnotation(spans.ASSEMBLE):
+            etas, metrics, naives, targets = self._cat_hist(outs)
+            if len(carry) == 4:  # watchdog carry (§13)
+                w_last, _, tail, fault_t = carry
+                ft = int(jax.device_get(fault_t))
+                fault_round = ft if ft >= 0 else None
+            else:
+                w_last, _, tail = carry
+                fault_round = None
+            return RunResult(
+                final_w=self._restore_params(jnp.mean(tail, axis=0)),
+                last_w=self._restore_params(w_last),
+                eta_history=etas,
+                metric_history=metrics,
+                eta_naive_history=naives,
+                eta_target_history=targets,
+                fault_round=fault_round,
+            )
 
     def _initial_carry(self, donate: bool):
         """Round-0 carry (w, algorithm state, tail window)."""
@@ -1019,11 +1031,12 @@ class FederatedSession:
         return (w, self.algorithm.init_state(w),
                 jnp.zeros((self._tail_n(),) + w.shape, w.dtype))
 
-    def lower(self, key: jax.Array) -> jax.stages.Lowered:
-        """Lower, without running, the first compiled round program that an
-        untracked ``run(key)`` executes (the chunk starting at round 0), e.g.
-        to read its HLO.  The eager engine and host-resident sources run no
-        single program and raise."""
+    def lower(self, key: jax.Array, *, tap: bool = False) -> jax.stages.Lowered:
+        """Lower, without running, the first compiled round program that
+        ``run(key)`` executes (the chunk starting at round 0), e.g. to read
+        its HLO: the untracked program, or with ``tap`` the one a run with a
+        tracker executes (§15).  The eager engine and host-resident sources
+        run no single program and raise."""
         if self.engine.engine == "eager" or self._source is not None:
             raise ValueError("lower() needs a compiled scan or stream "
                              "engine on device-resident data")
@@ -1032,7 +1045,7 @@ class FederatedSession:
         carry = self._initial_carry(donate)
         if self._watchdog:
             carry = carry + (jnp.int32(-1),)
-        fn, batches, extra = self._chunk_callable(donate)
+        fn, batches, extra = self._chunk_callable(donate, tap=tap)
         start, stop = self._chunk_bounds(0, t.rounds, self.engine.chunk_rounds,
                                          None, self.telemetry.profile_rounds)[0]
         return fn.lower(carry, key, jnp.arange(start, stop, dtype=jnp.int32),
@@ -1091,9 +1104,12 @@ class FederatedSession:
                 sess = _tap_mod.active()
                 if tap and sess is not None:
                     sess.profile_event("start", s, prof_dir)
-            carry, chunk_outs = fn(carry, key,
-                                   jnp.arange(s, e, dtype=jnp.int32),
-                                   batches, *extra, eta_l)
+            with jax.profiler.TraceAnnotation(spans.DISPATCH,
+                                              call=self._calls,
+                                              rounds=f"{s}:{e}"):
+                carry, chunk_outs = fn(carry, key,
+                                       jnp.arange(s, e, dtype=jnp.int32),
+                                       batches, *extra, eta_l)
             fault_t = int(jax.device_get(carry[3])) if watchdog else -1
             if prof_active and e >= min(profile[1], t.rounds):
                 _prof_stop(e)

@@ -46,6 +46,11 @@ from repro.kernels.interpret import resolve_interpret
 
 __all__ = ["dp_aggregate_kernel_call", "ldp_noise_kernel_call"]
 
+# stable names of the two kernels: the HLO instruction of each Mosaic call
+# (``%dp_aggregate.N``) and so its device ops in a profiler trace
+KERNEL_NAME = "dp_aggregate"
+NOISE_KERNEL_NAME = "ldp_noise"
+
 _EPS = 1e-12
 _THREEFRY_C = 0x1BD11BDA     # Threefry key-schedule constant
 _GOLDEN = 0x9E3779B9         # second key word for the in-kernel PRF
@@ -225,6 +230,7 @@ def dp_aggregate_kernel_call(
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(meta_i, meta_f, *operands)
     sum_released, sq_rel, sq_clip = out
     return sum_released[0], sq_rel[0, 0], sq_clip[0, 0]
@@ -268,4 +274,5 @@ def ldp_noise_kernel_call(
         ),
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
         interpret=interpret,
+        name=NOISE_KERNEL_NAME,
     )(meta_i, meta_f)

@@ -32,7 +32,10 @@ from __future__ import annotations
 import math
 import time
 
+import jax
 import numpy as np
+
+from repro.telemetry import spans
 
 __all__ = ["TapSession", "install", "uninstall", "active", "device_emit",
            "PAYLOAD_LEN"]
@@ -48,8 +51,9 @@ _ACTIVE: "TapSession | None" = None
 class TapSession:
     def __init__(self, tracker, *, start_round: int = 0, ledger_fn=None,
                  faults_active: bool = False,
-                 bytes_per_round: float | None = None):
+                 bytes_per_round: float | None = None, call: int = 0):
         self.tracker = tracker
+        self.call = int(call)  # the session's run/resume count, for spans
         self.expected_t = int(start_round)
         self.ledger_fn = ledger_fn
         self.faults_active = faults_active
@@ -100,7 +104,7 @@ class TapSession:
             # watchdog froze the carry at fault_t; this round did not run
             event["frozen"] = True
             event["watchdog_fault_round"] = ft
-            self.tracker.log(t, event)
+            self._log(t, event)
             return
         self.executed += 1
         event.update(
@@ -129,7 +133,8 @@ class TapSession:
             # observability must never kill a run: an accounting failure
             # surfaces once as an event field and disables the ledger
             try:
-                rep = self.ledger_fn(self.executed)
+                with jax.profiler.TraceAnnotation(spans.LEDGER):
+                    rep = self.ledger_fn(self.executed)
             except Exception as e:  # noqa: BLE001 - deliberate firewall
                 event["ledger_error"] = repr(e)
                 self.ledger_fn = None
@@ -137,7 +142,11 @@ class TapSession:
                 event.update(
                     ledger_rounds=self.executed, mu=float(rep.mu),
                     eps=float(rep.eps_numerical), eps_rdp=float(rep.eps_rdp))
-        self.tracker.log(t, event)
+        self._log(t, event)
+
+    def _log(self, t: int, event: dict) -> None:
+        with jax.profiler.TraceAnnotation(spans.LOG):
+            self.tracker.log(t, event)
 
 
 def install(session: TapSession) -> None:
@@ -163,4 +172,6 @@ def device_emit(t, shard, vec) -> None:
     is dropped rather than crashed on."""
     s = _ACTIVE
     if s is not None:
-        s.emit(int(t), int(shard), np.asarray(vec))
+        with jax.profiler.TraceAnnotation(spans.EMIT, call=s.call,
+                                          round=int(t)):
+            s.emit(int(t), int(shard), np.asarray(vec))

@@ -9,11 +9,14 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every xdist worker imports
 this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.dp_aggregate.kernel import KERNEL_NAME, NOISE_KERNEL_NAME
 from repro.kernels.dp_aggregate.ops import dp_aggregate, generate_ldp_noise
 
 # (M, d): the paper's CDP CNN (d = 5,046 -> 5,120 lanes) and LDP CNN
@@ -55,8 +58,19 @@ def _lowered(mode, m, d, sharding):
         x, c, interpret=False).cbar).lower(u, scalar)
 
 
+def _kernel_lines(text: str, name: str) -> list[str]:
+    """The compiled HLO's Mosaic calls whose instruction is ``name.N``."""
+    return [line for line in text.splitlines()
+            if re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", line)
+            and 'custom_call_target="tpu_custom_call"' in line]
+
+
 @pytest.mark.parametrize("m,d", WIDTHS, ids=[f"{m}x{d}" for m, d in WIDTHS])
 @pytest.mark.parametrize("mode", MODES)
 def test_dp_aggregate_compiles_for_v5e(one_chip, mode, m, d):
-    compiled = _lowered(mode, m, d, one_chip).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = _lowered(mode, m, d, one_chip).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel keeps its stable name through compilation, so a profiler
+    # trace's device op is found by name, not as "the only Mosaic call"
+    name = NOISE_KERNEL_NAME if mode == "noise" else KERNEL_NAME
+    assert len(_kernel_lines(text, name)) == 1, name
