@@ -9,6 +9,15 @@ the unique choice making the flatten widths equal the stated FC fan-ins
 conv (the paper's table lists only the FC ReLU; a linear conv stack cannot
 learn the task — deviation noted).  Softmax is folded into the cross-entropy.
 
+Each convolution is written as its kh*kw strided slices of the input,
+concatenated into patches, and one matmul against the kernel reshaped to
+(kh*kw*c_in, c_out): the same sums as ``lax.conv_general_dilated``.  Under
+the engine's ``vmap`` over clients with per-client weights, a convolution
+becomes a grouped convolution whose weight gradient XLA lowers to a 3-D
+convolution dilated along the client axis, far from the chip's matmul unit;
+the patch matmul becomes a batched matmul with the client as batch, the form
+the FC layers already take.
+
 Parameter counts: CDP d = 5,046; LDP d = 237 — small enough that LDP noise
 O(d sigma^2) stays informative, matching the paper's LDP/CDP model split.
 """
@@ -27,10 +36,19 @@ __all__ = ["CNNModel", "make_cnn", "make_cnn_params", "masked_xent_loss",
 
 
 def _conv(x, w, b, stride):
-    y = jax.lax.conv_general_dilated(
-        x, w, window_strides=(stride, stride), padding="VALID",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    return y + b
+    """VALID ``stride``-strided convolution, NHWC input and HWIO kernel: one
+    (n*oh*ow, kh*kw*c_in) x (kh*kw*c_in, c_out) matmul of strided patches."""
+    n, h, wd, c_in = x.shape
+    kh, kw, _, c_out = w.shape
+    oh, ow = (h - kh) // stride + 1, (wd - kw) // stride + 1
+    patches = jnp.concatenate(
+        [jax.lax.slice(x, (0, i, j, 0),
+                       (n, i + (oh - 1) * stride + 1, j + (ow - 1) * stride + 1, c_in),
+                       (1, stride, stride, 1))
+         for i in range(kh) for j in range(kw)], axis=-1)
+    k = kh * kw * c_in
+    y = patches.reshape(n * oh * ow, k) @ w.reshape(k, c_out)
+    return y.reshape(n, oh, ow, c_out) + b
 
 
 def _forward(params, x):
