@@ -66,19 +66,16 @@ def main() -> None:
     use_compile_cache(cell_mod.CHECKOUT)
     require_tpu(entry["chips"])
     cell_mod.program_path()
-    import numpy as np
-
     from chipbench import compare, inputs, reference
     from chipbench.faults import FAULTS
 
     for seed in args.seeds:
-        inp = inputs.make_inputs(seed, cfg)
+        inp = cell_mod.family(cfg).make_inputs(seed, cfg)
         key = inputs.run_key(inp["runs_key"], 1)
         t0 = time.perf_counter()
         ref = reference.run(cfg, inp["w0"], inp["batches"], inp["test"], key,
                             rounds=traffic["rounds_per_call"])
         t_ref = time.perf_counter() - t0
-        w0 = {k: np.asarray(v) for k, v in inp["w0"].items()}
         runs = {"program": lambda: program_call(cfg, traffic, inp, key)}
         if args.control:
             runs["control"] = lambda: control_call(cfg, inp, key, traffic["rounds_per_call"])
@@ -90,7 +87,7 @@ def main() -> None:
         for name, call in runs.items():
             out = call()
             line = {"workload": args.workload, "seed": seed, "run": name,
-                    "reference_s": t_ref, **compare.readings(out, ref, w0)}
+                    "reference_s": t_ref, **compare.readings(out, ref, inp["w0"])}
             print(json.dumps(line), flush=True)
 
 

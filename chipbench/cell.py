@@ -1,13 +1,16 @@
-"""A cell of ``BENCHMARK.json``: its configuration, its traffic mix, and the
-program's ``FederatedSession`` built for them.
+"""A cell of ``BENCHMARK.json``: its configuration, its traffic mix, its
+model family, and the program's ``FederatedSession`` built for them.
 
 Everything that belongs to one configuration or one traffic mix is a data
 file found by name: ``configs/<config>.json`` and ``traffic/<traffic>.json``
-beside this module.  This is the only module that builds the system under
-test; it reaches the program through its public entry points alone.
+beside this module.  What depends on the model is the module of the
+family the configuration names, ``families/<family>.py``.  This is the only
+module that builds the system under test; it reaches the program through
+its public entry points alone.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import sys
 from pathlib import Path
@@ -37,6 +40,14 @@ def find(workload: str, bench: dict) -> tuple[dict, dict, dict]:
     return cell, cfg, traffic
 
 
+def family(cfg: dict):
+    """The module of the configuration's model family (``families/``)."""
+    if "family" not in cfg:
+        raise KeyError(f"configuration {cfg.get('name')!r} names no "
+                       f"\"family\"; chipbench/families/ has one module each")
+    return importlib.import_module(f"chipbench.families.{cfg['family']}")
+
+
 def program_path() -> None:
     """Make the program's package importable (it lives under ``src/``)."""
     src = str(CHECKOUT / "src")
@@ -48,15 +59,15 @@ def make_session(cfg: dict, traffic: dict, inputs: dict):
     """The session a user of the simulator would build for this cell.
 
     Scan engine, ``backend="auto"`` (on a TPU the Pallas ``dp_aggregate``
-    kernel clips, noises and reduces), default matmul precision, eval of the
-    test cross-entropy after every round.  ``traffic["chips"] > 1`` shards
-    the cohort over a ``clients`` mesh of that many chips.
+    kernel clips, noises and reduces), default matmul precision, the
+    family's ``program_loss`` for training and for the eval of the held-out
+    set after every round.  ``traffic["chips"] > 1`` shards the cohort over
+    a ``clients`` mesh of that many chips.
     """
     program_path()
     from repro.core.fedexp import make_algorithm
     from repro.fedsim import (EngineSpec, FederatedSession, ShardSpec,
                               TrainSpec)
-    from repro.models.cnn import pytree_xent_loss
 
     kwargs = dict(clip_norm=cfg["clip"], sigma=cfg["sigma"], backend="auto")
     if cfg["mechanism"] == "cdp":
@@ -67,7 +78,7 @@ def make_session(cfg: dict, traffic: dict, inputs: dict):
         from repro.launch.mesh import make_client_mesh
 
         mesh = make_client_mesh(traffic["chips"])
-    loss = pytree_xent_loss()
+    loss = family(cfg).program_loss(cfg)
     test = inputs["test"]
     return FederatedSession(
         algorithm, loss, inputs["w0"], inputs["batches"],

@@ -6,19 +6,20 @@ from the same initial weights, client data and run key, with the same
 release noise.  A cell compares the numbers that its
 ``limits/<workload>.json`` gives a limit:
 
-- ``loss_end``: the mean test cross-entropy of the last five rounds, gap as
-  a share of the test loss at w0: the whole call's training;
+- ``loss_end``: the mean held-out loss (the CNN's test cross-entropy) of
+  the last five rounds, gap as a share of the held-out loss at w0: the
+  whole call's training;
 - ``eta_target_r1``: round 1's eta_target (mean clipped squared norm over
   the noised mean's squared norm), relative gap.  Round 1 starts both runs
   from one w0, so it holds local training, clipping and the release of all
   M clients before later rounds amplify rounding;
 - ``change``: the change of the weights the call hands back (the average
   of the last ``avg_last`` iterates) from w0, by the worst weight kernel
-  (every leaf of two or more dimensions): the gap between the two runs'
-  norms of that leaf's change, over the larger of the reference's norm of
-  it and of the median leaf's.  The biases (4 to 32 entries) are left out:
-  their change over 50 rounds is the sum of few noisy coordinates
-  (``PERF.md`` section 6).
+  (every leaf of the weight pytree with two or more dimensions, paired by
+  key path): the gap between the two runs' norms of that leaf's change,
+  over the larger of the reference's norm of it and of the median leaf's.
+  The biases (4 to 32 entries in the CNN) are left out: their change over
+  50 rounds is the sum of few noisy coordinates (``PERF.md`` section 6).
 
 Beside them, where the cell streams telemetry, an exact check with the
 limit 0: each call's stream holds each round once, in order, with the eta,
@@ -31,6 +32,7 @@ import json
 import math
 from pathlib import Path
 
+import jax
 import numpy as np
 
 from chipbench import reference
@@ -47,20 +49,26 @@ def rel(a: float, b: float) -> float:
     return abs(float(a) - float(b)) / abs(float(b))
 
 
-def change_gap(w: dict, w_ref: dict, w0: dict) -> float:
+def leaves(tree) -> dict[str, np.ndarray]:
+    """Key path -> leaf, as float64 NumPy, of a weight pytree."""
+    return {jax.tree_util.keystr(path): np.asarray(leaf, np.float64)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def change_gap(w, w_ref, w0) -> float:
     """Worst weight kernel's gap between the norms of the two runs' changes,
     over the larger of its reference norm and the median leaf's."""
+    start = leaves(w0)
+
     def norms(a):
-        return {k: float(np.linalg.norm(np.asarray(a[k], np.float64)
-                                         - np.asarray(w0[k], np.float64)))
-                for k in w0}
+        return {k: float(np.linalg.norm(v - start[k])) for k, v in leaves(a).items()}
     got, ref = norms(w), norms(w_ref)
     floor = float(np.median(list(ref.values())))
     return max(abs(got[k] - ref[k]) / max(ref[k], floor)
-               for k in w0 if np.ndim(w0[k]) >= 2)
+               for k, v in start.items() if v.ndim >= 2)
 
 
-def readings(out: dict, ref: dict, w0: dict) -> dict[str, float]:
+def readings(out: dict, ref: dict, w0) -> dict[str, float]:
     """The compared numbers of one program call against one reference run."""
     h = ref["history"]
     return {
@@ -110,9 +118,9 @@ def check(workload: str, cfg: dict, inp: dict, key, out: dict | None,
     lim = limits(workload)
     ref = reference.run(cfg, inp["w0"], inp["batches"], inp["test"], key,
                         rounds=len(out["eta_history"]))
-    w0 = {k: np.asarray(v) for k, v in inp["w0"].items()}
     checks = {name: {"value": value, "limit": lim[name]}
-              for name, value in readings(out, ref, w0).items() if name in lim}
+              for name, value in readings(out, ref, inp["w0"]).items()
+              if name in lim}
     if streams is not None:
         bad = sum(stream_errors(lines, o, cfg["clients"]) for lines, o in streams)
         checks["stream_errors"] = {"value": bad, "limit": 0}

@@ -6,10 +6,11 @@
 Builds the cell's session as ``run.py`` does, warms it up, runs one window
 of back-to-back calls untraced and one traced (``run.measure``, both
 ``--seconds`` long), then reads the traced window by the program's named
-scopes and host spans (``scopes.py``).  Its last stdout line is one JSON
-object: both windows' rounds a second (their ratio is what tracing costs),
-the cell's per-layer metrics, read by ``metrics/<name>.py`` as the traced
-run of ``run.py`` reads its own, and the breakdown, which is on stderr too.
+scopes and host spans (``run.window_scopes``, as the traced run of
+``run.py`` reads them).  Its last stdout line is one JSON object: both
+windows' rounds a second (their ratio is what tracing costs), the cell's
+``per_layer`` metrics of ``BENCHMARK.json``, read by ``metrics/<name>.py``
+as ``run.py`` reads them, and the scope breakdown, which is on stderr too.
 
 ``--rounds`` shortens the cell's calls and ``--save PREFIX`` writes the
 traced window's ``.xplane.pb`` and the round program's HLO text, gzipped,
@@ -22,13 +23,11 @@ from __future__ import annotations
 import argparse
 import glob
 import gzip
-import importlib
 import json
 import os
 import shutil
 import sys
 import tempfile
-import time
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -39,28 +38,11 @@ from chipbench.run import (  # noqa: E402
     CompileCounter,
     log,
     measure,
+    read_metrics,
     require_tpu,
     use_compile_cache,
+    window_scopes,
 )
-
-# the benchmark's per-layer metrics, and those that read the program's
-# scopes and spans: the tap's only where a tracker is attached
-TRACE_METRICS = ("mfu", "device_idle_share", "dp_aggregate_us_per_round")
-LAYER_METRICS = ("local_update_ms_per_round", "release_us_per_round",
-                 "server_step_us_per_round")
-TAP_METRICS = ("tap_ms_per_round", "tap_host_ms_per_round")
-
-
-def layer_metrics(ctx: dict, tracked: bool) -> dict:
-    """Each metric the cell reads, by its reader ``metrics/<name>.py``; a
-    tracked cell's names take the ``.telemetry`` suffix, as in
-    ``BENCHMARK.json``."""
-    out = {}
-    for name in TRACE_METRICS + LAYER_METRICS + (TAP_METRICS if tracked else ()):
-        value = importlib.import_module(f"chipbench.metrics.{name}").read(ctx)
-        if value is not None:
-            out[name + (".telemetry" if tracked else "")] = value
-    return out
 
 
 def save(prefix: str, trace_dir: str, hlo_text: str | None) -> None:
@@ -83,19 +65,20 @@ def main(argv=None) -> dict:
     ap.add_argument("--rounds", type=int, default=None)
     ap.add_argument("--save", default=None)
     args = ap.parse_args(argv)
-    entry, cfg, traffic = cell_mod.find(args.workload, cell_mod.benchmark())
+    bench = cell_mod.benchmark()
+    entry, cfg, traffic = cell_mod.find(args.workload, bench)
     use_compile_cache(cell_mod.CHECKOUT)
     devices = require_tpu(entry["chips"])
     cell_mod.program_path()
     import jax
 
-    from chipbench import inputs, scopes, trace
+    from chipbench import inputs, trace
     from chipbench.peaks import peaks
 
     if args.rounds is not None:
         traffic = dict(traffic, rounds_per_call=args.rounds)
     tracked = bool(traffic["tracker"])
-    inp = inputs.make_inputs(args.seed, cfg)
+    inp = cell_mod.family(cfg).make_inputs(args.seed, cfg)
     session = cell_mod.make_session(cfg, traffic, inp)
     scratch = tempfile.mkdtemp(prefix="chipbench-layers-")
     try:
@@ -117,21 +100,16 @@ def main(argv=None) -> dict:
             log(f"{name} window {w['window_s']:.3f} s: {w['calls']} calls, "
                 f"{w['failed']} failed, {w['rounds']} rounds, "
                 f"{w['rounds'] / w['window_s']:.4f} rounds/s")
-        t_hlo = time.perf_counter()
-        hlo_text = session.lower(inputs.run_key(inp["runs_key"], 0),
-                                 tap=tracked).compile().as_text()
-        log(f"round program HLO in {time.perf_counter() - t_hlo:.3f} s "
-            "(JAX's in-process cache holds the executable the windows ran)")
         trace_dir = os.path.join(scratch, "trace")
         profile = trace.load(trace_dir)
         traced = windows["traced"]
         ctx = {"cfg": cfg, "traffic": traffic, "rounds": traced["rounds"],
                "window_s": traced["window_s"], "chips": entry["chips"],
                "peaks": peaks(devices[0].device_kind),
-               "trace": trace.reduce(profile, chips=entry["chips"]),
-               "scopes": scopes.reduce(profile, hlo_text, chips=entry["chips"])}
-        for line in scopes.summary(ctx["scopes"], ctx["rounds"]):
-            log(line)
+               "trace": trace.reduce(profile, chips=entry["chips"])}
+        ctx["scopes"], hlo_text = window_scopes(
+            session, inp["runs_key"], tracked, profile, chips=entry["chips"],
+            rounds=traced["rounds"])
         if args.save:
             save(args.save, trace_dir, hlo_text)
     finally:
@@ -142,7 +120,7 @@ def main(argv=None) -> dict:
         "compiles_in_windows": counter.count,
         "rounds_per_s": {name: w["rounds"] / w["window_s"]
                          for name, w in windows.items()},
-        "metrics": layer_metrics(ctx, tracked),
+        "metrics": read_metrics(bench["per_layer"], args.workload, ctx),
         "scopes": ctx["scopes"]}
     print(json.dumps(result), flush=True)
     return result

@@ -40,7 +40,7 @@ def main() -> None:
     from chipbench import inputs
 
     traffic = dict(traffic, rounds_per_call=args.rounds)
-    inp = inputs.make_inputs(5, cfg)
+    inp = cell_mod.family(cfg).make_inputs(5, cfg)
     session = cell_mod.make_session(cfg, traffic, inp)
     jax.block_until_ready(session.run(inputs.run_key(inp["runs_key"], 0)).final_w)
     with tempfile.TemporaryDirectory() as tmp:

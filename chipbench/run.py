@@ -4,7 +4,8 @@
     python3 chipbench/run.py --workload e2-cdp-cnn.full --seed 7 --seconds 20 --trace 0
 
 A cell (``BENCHMARK.json``, ``workloads``) names a configuration
-(``chipbench/configs/<config>.json``) and a traffic mix
+(``chipbench/configs/<config>.json``, which names its model family,
+``chipbench/families/<family>.py``) and a traffic mix
 (``chipbench/traffic/<traffic>.json``).  The run builds the cell's inputs
 from ``--seed``, builds the program's ``FederatedSession`` for them, warms
 it up with one ``session.run`` (which compiles, or reads the compile cache),
@@ -16,7 +17,10 @@ followed by the plain reference (``reference.py``) and compared
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` traces
 the window with the JAX profiler and prints its per-layer metrics, each read
-by ``chipbench/metrics/<name>.py`` from the reduced trace (``trace.py``).
+by ``chipbench/metrics/<name>.py`` from the reduced trace (``trace.py``) or
+from the device time of the program's named scopes and the durations of its
+host spans (``scopes.py``, which maps the trace's ops to scopes through the
+round program's compiled HLO, fetched after the window).
 The last line of stdout is one JSON object; each number that ``correct``
 compares is printed beside its limit, last on stderr and last in that
 object.  Without a TPU, or with fewer chips than the cell asks for, the run
@@ -115,13 +119,14 @@ def to_host(result) -> dict:
 
 
 def finite(out: dict) -> bool:
-    """Every weight and every recorded history entry is finite.  CDP has no
-    naive eta: that history is NaN throughout by design."""
+    """Every weight (each leaf of the weight pytrees) and every recorded
+    history entry is finite.  CDP has no naive eta: that history is NaN
+    throughout by design."""
+    import jax
     import numpy as np
 
     for name, value in out.items():
-        a = np.asarray(value) if not isinstance(value, dict) else np.concatenate(
-            [np.ravel(v) for v in value.values()])
+        a = np.concatenate([np.ravel(v) for v in jax.tree_util.tree_leaves(value)])
         if name == "eta_naive_history" and np.all(np.isnan(a)):
             continue
         if not np.all(np.isfinite(a)):
@@ -185,6 +190,33 @@ def measure(session, traffic: dict, runs_key, seconds: float, *, tracker_dir,
             "rounds": rounds * len(outs), "outs": outs}
 
 
+def window_scopes(session, runs_key, tracked: bool, profile, *, chips: int,
+                  rounds: int) -> tuple[dict, str | None]:
+    """The traced window by the program's named scopes and host spans
+    (``scopes.reduce``), and the round program's HLO text it was read
+    through; the summary goes to stderr.
+
+    The text is that of the compiled round program the window ran: JAX's
+    in-process cache hands back its executable, so its instruction names
+    are the trace's.  A program whose ``lower`` takes no ``tap`` (one older
+    than its named scopes) gives no text, and no scope readings."""
+    from chipbench import scopes
+    from chipbench.inputs import run_key
+
+    t0 = time.perf_counter()
+    try:
+        lowered = session.lower(run_key(runs_key, 0), tap=tracked)
+        hlo = lowered.compile().as_text()
+    except TypeError as e:
+        log(f"no round program HLO, so no scope readings: {e}")
+        hlo = None
+    log(f"round program HLO in {time.perf_counter() - t0:.3f} s")
+    reduced = scopes.reduce(profile, hlo, chips=chips)
+    for line in scopes.summary(reduced, rounds):
+        log(line)
+    return reduced, hlo
+
+
 def memory_peak(devices) -> int | None:
     peaks = [d.memory_stats().get("peak_bytes_in_use") for d in devices
              if d.memory_stats()]
@@ -221,7 +253,7 @@ def run_cell(workload: str, entry: dict, cfg: dict, traffic: dict, *,
 
     counter = CompileCounter()
     log(f"{time.perf_counter() - T_PROCESS:.3f} s: JAX on {devices[0].device_kind}")
-    inp = inputs.make_inputs(seed, cfg)
+    inp = cell_mod.family(cfg).make_inputs(seed, cfg)
     jax.block_until_ready(inp)
     log(f"{time.perf_counter() - T_PROCESS:.3f} s: inputs made")
     scratch = tempfile.mkdtemp(prefix="chipbench-")
@@ -247,7 +279,6 @@ def run_cell(workload: str, entry: dict, cfg: dict, traffic: dict, *,
             f"{window['failed']} failed, {window['rounds']} rounds; "
             f"compiles in the window: {counter.count}")
         peak_bytes = memory_peak(devices[:entry["chips"]])
-        del session, warm
 
         device = {"platform": devices[0].platform,
                   "kind": devices[0].device_kind, "count": len(devices),
@@ -257,14 +288,19 @@ def run_cell(workload: str, entry: dict, cfg: dict, traffic: dict, *,
                "window_s": window["window_s"], "setup_s": setup_s,
                "chips": entry["chips"]}
         if trace_on:
-            reduced = trace.reduce(trace.load(trace_dir), chips=entry["chips"])
+            profile = trace.load(trace_dir)
+            reduced = trace.reduce(profile, chips=entry["chips"])
             device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
             ctx.update(trace=reduced, peaks=peaks(devices[0].device_kind))
+            ctx["scopes"], _ = window_scopes(
+                session, inp["runs_key"], bool(traffic["tracker"]), profile,
+                chips=entry["chips"], rounds=window["rounds"])
             result["metrics"] = read_metrics(bench["per_layer"], workload, ctx)
             result["breakdown"] = reduced["breakdown"]
         else:
             result["metrics"] = read_metrics(bench["end_to_end"], workload, ctx)
         result["device"] = device
+        del session, warm
 
         last = window["outs"][-1] if window["outs"] else (None, None)
         streams = None

@@ -62,17 +62,14 @@ def test_a_planted_fault_is_not_correct(fault):
 
 
 def test_the_bfloat16_control_is_not_correct():
-    import numpy as np
-
     from chipbench import calibrate, inputs, reference
 
     _, cfg, traffic, _ = small("e2-cdp-cnn.full")
-    inp = inputs.make_inputs(SEED, cfg)
+    inp = cell_mod.family(cfg).make_inputs(SEED, cfg)
     key = inputs.run_key(inp["runs_key"], 1)
     rounds = traffic["rounds_per_call"]
     ref = reference.run(cfg, inp["w0"], inp["batches"], inp["test"], key, rounds=rounds)
     control = calibrate.control_call(cfg, inp, key, rounds)
-    w0 = {k: np.asarray(v) for k, v in inp["w0"].items()}
-    got = compare.readings(control, ref, w0)
+    got = compare.readings(control, ref, inp["w0"])
     lim = compare.limits("e2-cdp-cnn.full")
     assert any(got[k] > lim[k] for k in lim), (got, lim)

@@ -38,6 +38,7 @@ def test_every_file_a_cell_names_exists():
     for c in SPEC["configs"]:
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
+        assert (BENCH / "families" / f"{cfg['family']}.py").exists()
     for w in SPEC["workloads"]:
         traffic = json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text())
         assert traffic["chips"] == w["chips"]
@@ -48,6 +49,16 @@ def test_every_file_a_cell_names_exists():
         assert (BENCH / "metrics" / f"{m['name'].split('.')[0]}.py").exists()
         for w in m.get("workloads", []):
             assert w in {c["name"] for c in SPEC["workloads"]}
+
+
+def test_a_configuration_without_a_family_is_refused():
+    from chipbench import cell
+
+    cfg = json.loads((ROOT / SPEC["configs"][0]["file"]).read_text())
+    assert cell.family(cfg).__name__ == f"chipbench.families.{cfg['family']}"
+    del cfg["family"]
+    with pytest.raises(KeyError, match="names no"):
+        cell.family(cfg)
 
 
 def test_sigma_follows_the_configuration_rule():

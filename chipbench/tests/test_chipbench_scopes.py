@@ -123,3 +123,64 @@ def test_idle_gaps_name_the_innermost_program_span():
                     ["fedsim.run", 10e-9]]
     assert scopes.idle_gaps([], 0, 10, [], top=1) == [["no program span", 10e-9]]
 
+
+
+class RecordedSession:
+    """Stands in for the session after the window: lowers to the recorded
+    round program."""
+
+    def __init__(self, text: str):
+        self.text = text
+
+    def lower(self, key, *, tap: bool):
+        assert tap
+        return self
+
+    def compile(self):
+        return self
+
+    def as_text(self) -> str:
+        return self.text
+
+
+class OlderSession:
+    """A session whose ``lower`` takes no ``tap``."""
+
+    def lower(self, key):
+        raise AssertionError("not reached")
+
+
+def test_the_traced_run_reads_every_per_layer_metric_of_its_cell():
+    import jax
+
+    from chipbench import cell as cell_mod, run
+    from chipbench.peaks import peaks
+
+    prof = profile(f"{TRACKED}.xplane.pb.gz")
+    text = gzip.open(DATA / f"{TRACKED}.hlo.txt.gz", "rt").read()
+    bench = cell_mod.benchmark()
+    workload = "e2-cdp-cnn.telemetry"
+    _, cfg, traffic = cell_mod.find(workload, bench)
+    rounds = 2 * sum(1 for _, _, n in trace.host_spans(prof) if n == trace.WINDOW_SPAN)
+    reduced = trace.reduce(prof, chips=1)
+    got, hlo = run.window_scopes(RecordedSession(text), jax.random.PRNGKey(0),
+                                 True, prof, chips=1, rounds=rounds)
+    assert hlo == text and got == scopes.reduce(prof, text, chips=1)
+    ctx = {"cfg": cfg, "traffic": traffic, "rounds": rounds, "chips": 1,
+           "window_s": reduced["window_s"], "peaks": peaks("TPU v5 lite"),
+           "trace": reduced, "scopes": got}
+    metrics = run.read_metrics(bench["per_layer"], workload, ctx)
+    assert set(metrics) == {m["name"] for m in bench["per_layer"]
+                            if workload in m["workloads"]}
+    assert all(m["value"] > 0 for m in metrics.values())
+
+
+def test_a_program_whose_lower_takes_no_tap_gives_no_scope_readings(untagged):
+    import jax
+
+    from chipbench import run
+
+    got, hlo = run.window_scopes(OlderSession(), jax.random.PRNGKey(0), False,
+                                 profile("e2-cdp-cnn.full.r2.xplane.pb.gz"),
+                                 chips=1, rounds=4)
+    assert hlo is None and got == untagged

@@ -11,9 +11,13 @@ window is the span of the harness's ``chipbench.call`` annotations on the
 host plane.
 
 - busy: the union of the op intervals on a chip, clipped to the window;
-- the ``dp_aggregate`` kernel: ops whose HLO is a Mosaic custom call
-  (``custom_call_target="tpu_custom_call"``), the only Pallas kernel on the
-  round's path;
+- the ``dp_aggregate`` kernel: the Mosaic custom calls
+  (``custom_call_target="tpu_custom_call"``) whose HLO instruction bears the
+  kernel's stable name, ``%dp_aggregate.N`` (the ``pallas_call``'s
+  ``name``, which ``tests/test_tpu_compile.py`` pins).  Another Pallas
+  kernel on the round's path, such as a model family's attention or scan
+  kernel, is not counted; nor is the kernel of a program older than that
+  name (``%_impl.N``), which then reads nothing;
 - idle gaps: the stretches of the window with no op on the first chip,
   named by the innermost host annotation that spans their middle.
 """
@@ -27,6 +31,7 @@ from collections import defaultdict
 WINDOW_SPAN = "chipbench.call"
 OPS_LINE = "XLA Ops"
 CONTAINERS = ("while", "conditional", "call")
+DP_AGGREGATE = "dp_aggregate"
 
 
 def load(trace_dir: str):
@@ -51,8 +56,10 @@ def op_kind(name: str) -> str:
     return re.sub(r"(\.\d+)+$", "", name)
 
 
-def is_kernel(text: str) -> bool:
-    return 'custom_call_target="tpu_custom_call"' in text
+def is_dp_aggregate(text: str) -> bool:
+    """The op is a Mosaic call of the ``dp_aggregate`` kernel."""
+    return (op_kind(op_name(text)) == DP_AGGREGATE
+            and 'custom_call_target="tpu_custom_call"' in text)
 
 
 def is_container(text: str) -> bool:
@@ -93,7 +100,7 @@ def device_planes(profile) -> list:
 
 
 def reduce(profile, *, chips: int, top: int = 10) -> dict:
-    """Busy and idle time, kernel time, and the breakdown."""
+    """Busy and idle time, ``dp_aggregate`` time, and the breakdown."""
     spans = host_spans(profile)
     window = [(s, e) for s, e, n in spans if n == WINDOW_SPAN]
     if not window:
@@ -118,16 +125,18 @@ def reduce(profile, *, chips: int, top: int = 10) -> dict:
                 dur = (min(ev.end_ns, hi) - max(ev.start_ns, lo)) * 1e-9
                 if not is_container(ev.name):
                     op_time[op_name(ev.name)] += dur / chips
-                if is_kernel(ev.name):
+                if is_dp_aggregate(ev.name):
                     kernel_s, kernel_n = kernel_s + dur, kernel_n + 1
         busy = union(intervals, lo, hi)
         if k == 0:
             first_busy = busy
         per_chip.append({"busy_s": sum(e - s for s, e in busy) * 1e-9,
-                         "kernel_s": kernel_s, "kernel_events": kernel_n})
+                         "dp_aggregate_s": kernel_s,
+                         "dp_aggregate_events": kernel_n})
     mean = lambda key: sum(c[key] for c in per_chip) / len(per_chip)
     return {"window_s": (hi - lo) * 1e-9, "busy_s": mean("busy_s"),
-            "kernel_s": mean("kernel_s"), "kernel_events": mean("kernel_events"),
+            "dp_aggregate_s": mean("dp_aggregate_s"),
+            "dp_aggregate_events": mean("dp_aggregate_events"),
             "per_chip": per_chip,
             "breakdown": {
                 "device_ops": sorted(([n, t] for n, t in op_time.items()),
